@@ -61,19 +61,25 @@ def time_embedding(t, dim=256, max_period=1e4):
 
 
 def denoise(params, cfg: ModelConfig, x, t, **fw_kwargs):
-    """x: [B, S, latent_dim]; t: scalar in [0,1]. Returns velocity [B,S,latent]."""
+    """x: [B, S, latent_dim]; t: scalar in [0,1]. Returns velocity [B,S,latent].
+
+    The in-projection with the time embedding and the output norm with the
+    out-projection run under the named scopes ``wrapper.in`` and
+    ``wrapper.out`` (``repro.obs.scopes``)."""
     dt_ = jnp.dtype(cfg.compute_dtype)
-    h = jnp.einsum("bsl,ld->bsd", x.astype(dt_), params["in_proj"].astype(dt_))
-    te = time_embedding(t)  # [256]
-    te = jax.nn.silu(te @ params["t_mlp1"].astype(jnp.float32))
-    te = te @ params["t_mlp2"].astype(jnp.float32)
-    h = h + te.astype(dt_)
+    with jax.named_scope("wrapper.in"):
+        h = jnp.einsum("bsl,ld->bsd", x.astype(dt_), params["in_proj"].astype(dt_))
+        te = time_embedding(t)  # [256]
+        te = jax.nn.silu(te @ params["t_mlp1"].astype(jnp.float32))
+        te = te @ params["t_mlp2"].astype(jnp.float32)
+        h = h + te.astype(dt_)
     h = model_api.forward_hidden(params["backbone"], cfg, h, causal=False, **fw_kwargs)
-    hf = h.astype(jnp.float32)
-    hf = hf * jax.lax.rsqrt(jnp.mean(hf * hf, -1, keepdims=True) + cfg.norm_eps)
-    hf = hf * params["out_norm"].astype(jnp.float32)
-    return jnp.einsum("bsd,dl->bsl", hf, params["out_proj"].astype(jnp.float32)).astype(
-        x.dtype)
+    with jax.named_scope("wrapper.out"):
+        hf = h.astype(jnp.float32)
+        hf = hf * jax.lax.rsqrt(jnp.mean(hf * hf, -1, keepdims=True) + cfg.norm_eps)
+        hf = hf * params["out_norm"].astype(jnp.float32)
+        return jnp.einsum("bsd,dl->bsl", hf, params["out_proj"].astype(jnp.float32)).astype(
+            x.dtype)
 
 
 def make_drift(params, cfg: ModelConfig, **fw_kwargs) -> ParamDrift:

@@ -39,31 +39,38 @@ def _block(cfg: ModelConfig, p, h, positions, causal, attn_impl, cache=None,
            cur_len=None):
     """One transformer block. Returns (h, new_kv or None).
 
+    Its parts run under the named scopes ``norm``, ``attn`` and ``mlp``
+    (``repro.obs.scopes``), which name them in compiled ops' metadata.
+
     ``cfg.use_kernels`` routes the norms and the (non-decode) attention
     through the Pallas kernel library (``repro.kernels``); positions here
     are 0-based aranges, which is the flash kernel's causal contract.
     """
     uk = cfg.use_kernels
-    x = L.rmsnorm(h, p["ln1"], cfg.norm_eps, use_kernel=uk)
-    q, k, v = L.qkv_proj(p["attn"], cfg, x, positions)
-    new_kv = None
-    if cache is not None and cur_len is not None:  # decode: append to cache
-        k_cache, v_cache = cache
-        idx = cur_len[0]  # uniform position across batch (batched decode)
-        k_cache = jax.lax.dynamic_update_slice_in_dim(k_cache, k.astype(k_cache.dtype), idx, axis=1)
-        v_cache = jax.lax.dynamic_update_slice_in_dim(v_cache, v.astype(v_cache.dtype), idx, axis=1)
-        attn = L.attend_decode(q, k_cache, v_cache, cur_len + 1)
-        new_kv = (k_cache, v_cache)
-    else:
-        q_pos = positions[0] if cfg.mrope_sections else positions
-        attn = L.attend(q, k, v, q_pos, q_pos, causal, impl=attn_impl,
-                        use_kernel=uk)
-        if cache == "collect":
-            new_kv = (k, v)
-    h = h + L.out_proj(p["attn"], attn)
+    with jax.named_scope("norm"):
+        x = L.rmsnorm(h, p["ln1"], cfg.norm_eps, use_kernel=uk)
+    with jax.named_scope("attn"):
+        q, k, v = L.qkv_proj(p["attn"], cfg, x, positions)
+        new_kv = None
+        if cache is not None and cur_len is not None:  # decode: append to cache
+            k_cache, v_cache = cache
+            idx = cur_len[0]  # uniform position across batch (batched decode)
+            k_cache = jax.lax.dynamic_update_slice_in_dim(k_cache, k.astype(k_cache.dtype), idx, axis=1)
+            v_cache = jax.lax.dynamic_update_slice_in_dim(v_cache, v.astype(v_cache.dtype), idx, axis=1)
+            attn = L.attend_decode(q, k_cache, v_cache, cur_len + 1)
+            new_kv = (k_cache, v_cache)
+        else:
+            q_pos = positions[0] if cfg.mrope_sections else positions
+            attn = L.attend(q, k, v, q_pos, q_pos, causal, impl=attn_impl,
+                            use_kernel=uk)
+            if cache == "collect":
+                new_kv = (k, v)
+        h = h + L.out_proj(p["attn"], attn)
     h = shard_act(h, ("batch", "seq", "embed_act"))
-    x = L.rmsnorm(h, p["ln2"], cfg.norm_eps, use_kernel=uk)
-    h = h + L.mlp(p["mlp"], cfg, x)
+    with jax.named_scope("norm"):
+        x = L.rmsnorm(h, p["ln2"], cfg.norm_eps, use_kernel=uk)
+    with jax.named_scope("mlp"):
+        h = h + L.mlp(p["mlp"], cfg, x)
     h = shard_act(h, ("batch", "seq", "embed_act"))
     return h, new_kv
 
@@ -90,8 +97,9 @@ def forward_hidden(params, cfg: ModelConfig, embeds, positions=None, causal=Fals
     if remat:
         body = jax.checkpoint(body, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
     h, _ = jax.lax.scan(body, embeds, params["blocks"])
-    return L.rmsnorm(h, params["final_norm"], cfg.norm_eps,
-                     use_kernel=cfg.use_kernels)
+    with jax.named_scope("norm"):
+        return L.rmsnorm(h, params["final_norm"], cfg.norm_eps,
+                         use_kernel=cfg.use_kernels)
 
 
 def forward_train(params, cfg: ModelConfig, tokens, positions=None, attn_impl="auto",

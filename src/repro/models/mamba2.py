@@ -81,96 +81,103 @@ def _gated_norm(y, z, w, eps):
 
 
 def ssd_forward(p, cfg: ModelConfig, x, conv_state=None, ssm_state=None):
-    """Chunked SSD. x: [B, S, D] -> (y [B, S, D], (conv_state, ssm_state))."""
+    """Chunked SSD. x: [B, S, D] -> (y [B, S, D], (conv_state, ssm_state)).
+
+    Its parts run under the named scopes ``mamba2.in_proj``,
+    ``mamba2.conv``, ``mamba2.scan`` and ``mamba2.out``
+    (``repro.obs.scopes``)."""
     bsz, s, _ = x.shape
     din, n, h, hd = d_inner(cfg), cfg.ssm_state, num_ssm_heads(cfg), cfg.ssm_head_dim
     lc = min(cfg.ssm_chunk, s)
     assert s % lc == 0, (s, lc)
     nc = s // lc
 
-    proj = jnp.einsum("bsd,dk->bsk", x, p["in_proj"].astype(x.dtype))
-    z, xc, b_, c_, dt = _split(cfg, proj)
-    conv_in = jnp.concatenate([xc, b_, c_], axis=-1)
-    conv_out, new_conv = _depthwise_causal_conv(conv_in, p["conv_w"].astype(x.dtype),
-                                                conv_state)
-    conv_out = jax.nn.silu(conv_out)
-    xc = conv_out[..., :din]
-    b_ = conv_out[..., din : din + n]
-    c_ = conv_out[..., din + n :]
+    with jax.named_scope("mamba2.in_proj"):
+        proj = jnp.einsum("bsd,dk->bsk", x, p["in_proj"].astype(x.dtype))
+    with jax.named_scope("mamba2.conv"):
+        z, xc, b_, c_, dt = _split(cfg, proj)
+        conv_in = jnp.concatenate([xc, b_, c_], axis=-1)
+        conv_out, new_conv = _depthwise_causal_conv(conv_in, p["conv_w"].astype(x.dtype),
+                                                    conv_state)
+        conv_out = jax.nn.silu(conv_out)
+        xc = conv_out[..., :din]
+        b_ = conv_out[..., din : din + n]
+        c_ = conv_out[..., din + n :]
+    with jax.named_scope("mamba2.scan"):
+        dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"].astype(jnp.float32))
+        a = -jnp.exp(p["a_log"].astype(jnp.float32))  # [H]
+        loga = dt * a[None, None, :]  # [B, S, H]  (log decay, <= 0)
 
-    dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"].astype(jnp.float32))
-    a = -jnp.exp(p["a_log"].astype(jnp.float32))  # [H]
-    loga = dt * a[None, None, :]  # [B, S, H]  (log decay, <= 0)
+        xh = xc.reshape(bsz, nc, lc, h, hd)
+        bh = b_.reshape(bsz, nc, lc, n).astype(jnp.float32)
+        ch = c_.reshape(bsz, nc, lc, n).astype(jnp.float32)
+        dth = dt.reshape(bsz, nc, lc, h)
+        logc = loga.reshape(bsz, nc, lc, h)
+        xh = shard_act(xh, ("batch", None, None, "heads", None))
 
-    xh = xc.reshape(bsz, nc, lc, h, hd)
-    bh = b_.reshape(bsz, nc, lc, n).astype(jnp.float32)
-    ch = c_.reshape(bsz, nc, lc, n).astype(jnp.float32)
-    dth = dt.reshape(bsz, nc, lc, h)
-    logc = loga.reshape(bsz, nc, lc, h)
-    xh = shard_act(xh, ("batch", None, None, "heads", None))
+        mask = jnp.tril(jnp.ones((lc, lc), bool))
+        init = (jnp.zeros((bsz, h, hd, n), jnp.float32) if ssm_state is None
+                else ssm_state.astype(jnp.float32))
 
-    mask = jnp.tril(jnp.ones((lc, lc), bool))
-    init = (jnp.zeros((bsz, h, hd, n), jnp.float32) if ssm_state is None
-            else ssm_state.astype(jnp.float32))
+        mode = resolve_kernel_mode(cfg.use_kernels)
+        if mode is not None:
+            # Pallas intra-chunk path (repro.kernels.ssd_scan): every chunk's
+            # masked decay-attention block and chunk-local state run in one
+            # kernel launch over a (batch*chunks, heads) grid; only the tiny
+            # [B, H, hd, N] inter-chunk recurrence stays in the scan below.
+            from repro.kernels.ssd_scan.kernel import ssd_chunk
+            cum = jnp.cumsum(logc, axis=2)                  # [B,nc,Lc,H]
+            total = cum[:, :, -1, :]                        # [B,nc,H]
+            xdt = xh.astype(jnp.float32) * dth[..., None]   # [B,nc,Lc,H,hd]
+            gdim = bsz * nc
+            y_k, s_k = ssd_chunk(
+                ch.reshape(gdim, lc, n), bh.reshape(gdim, lc, n),
+                xdt.transpose(0, 1, 3, 2, 4).reshape(gdim, h, lc, hd),
+                cum.transpose(0, 1, 3, 2).reshape(gdim, h, lc),
+                interpret=mode)
+            y_intra = y_k.reshape(bsz, nc, h, lc, hd).transpose(0, 1, 3, 2, 4)
+            s_local = s_k.reshape(bsz, nc, h, hd, n)
 
-    mode = resolve_kernel_mode(cfg.use_kernels)
-    if mode is not None:
-        # Pallas intra-chunk path (repro.kernels.ssd_scan): every chunk's
-        # masked decay-attention block and chunk-local state run in one
-        # kernel launch over a (batch*chunks, heads) grid; only the tiny
-        # [B, H, hd, N] inter-chunk recurrence stays in the scan below.
-        from repro.kernels.ssd_scan.kernel import ssd_chunk
-        cum = jnp.cumsum(logc, axis=2)                  # [B,nc,Lc,H]
-        total = cum[:, :, -1, :]                        # [B,nc,H]
-        xdt = xh.astype(jnp.float32) * dth[..., None]   # [B,nc,Lc,H,hd]
-        gdim = bsz * nc
-        y_k, s_k = ssd_chunk(
-            ch.reshape(gdim, lc, n), bh.reshape(gdim, lc, n),
-            xdt.transpose(0, 1, 3, 2, 4).reshape(gdim, h, lc, hd),
-            cum.transpose(0, 1, 3, 2).reshape(gdim, h, lc),
-            interpret=mode)
-        y_intra = y_k.reshape(bsz, nc, h, lc, hd).transpose(0, 1, 3, 2, 4)
-        s_local = s_k.reshape(bsz, nc, h, hd, n)
+            def body(carry, inp):
+                y_i, s_l, cum_c, ch_c, total_c = inp
+                y_inter = jnp.einsum("blh,bln,bhpn->blhp", jnp.exp(cum_c),
+                                     ch_c, carry)
+                new = jnp.exp(total_c)[:, :, None, None] * carry + s_l
+                return new, (y_i + y_inter).astype(x.dtype)
 
-        def body(carry, inp):
-            y_i, s_l, cum_c, ch_c, total_c = inp
-            y_inter = jnp.einsum("blh,bln,bhpn->blhp", jnp.exp(cum_c),
-                                 ch_c, carry)
-            new = jnp.exp(total_c)[:, :, None, None] * carry + s_l
-            return new, (y_i + y_inter).astype(x.dtype)
+            xs = tuple(jnp.moveaxis(t, 1, 0)
+                       for t in (y_intra, s_local, cum, ch, total))
+            final_state, y = jax.lax.scan(body, init, xs)
+        else:
+            def body(carry, inp):
+                # carry: inter-chunk state [B,H,hd,N]; one chunk's tensors:
+                xh_c, bh_c, ch_c, dth_c, logc_c = inp
+                cum = jnp.cumsum(logc_c, axis=1)  # [B,Lc,H]
+                total = cum[:, -1, :]  # [B,H]
+                xdt = xh_c.astype(jnp.float32) * dth_c[..., None]  # [B,Lc,H,hd]
+                # intra-chunk: G[l,m] = C_l . B_m ; M[h,l,m] = exp(cum_l - cum_m),
+                # m<=l
+                g = jnp.einsum("bln,bmn->blm", ch_c, bh_c)
+                dlog = cum[:, :, None, :] - cum[:, None, :, :]  # [B,Lc(l),Lc(m),H]
+                mexp = jnp.where(mask[None, :, :, None], jnp.exp(dlog), 0.0)
+                y_intra = jnp.einsum("blm,blmh,bmhp->blhp", g, mexp, xdt)
+                # inter-chunk contribution from the carried state
+                y_inter = jnp.einsum("blh,bln,bhpn->blhp", jnp.exp(cum), ch_c,
+                                     carry)
+                # chunk-local state + recurrence
+                w_local = jnp.exp(total[:, None, :] - cum)  # [B,Lc,H]
+                s_local = jnp.einsum("bmh,bmhp,bmn->bhpn", w_local, xdt, bh_c)
+                new = jnp.exp(total)[:, :, None, None] * carry + s_local
+                return new, (y_intra + y_inter).astype(x.dtype)
 
-        xs = tuple(jnp.moveaxis(t, 1, 0)
-                   for t in (y_intra, s_local, cum, ch, total))
-        final_state, y = jax.lax.scan(body, init, xs)
-    else:
-        def body(carry, inp):
-            # carry: inter-chunk state [B,H,hd,N]; one chunk's tensors:
-            xh_c, bh_c, ch_c, dth_c, logc_c = inp
-            cum = jnp.cumsum(logc_c, axis=1)  # [B,Lc,H]
-            total = cum[:, -1, :]  # [B,H]
-            xdt = xh_c.astype(jnp.float32) * dth_c[..., None]  # [B,Lc,H,hd]
-            # intra-chunk: G[l,m] = C_l . B_m ; M[h,l,m] = exp(cum_l - cum_m),
-            # m<=l
-            g = jnp.einsum("bln,bmn->blm", ch_c, bh_c)
-            dlog = cum[:, :, None, :] - cum[:, None, :, :]  # [B,Lc(l),Lc(m),H]
-            mexp = jnp.where(mask[None, :, :, None], jnp.exp(dlog), 0.0)
-            y_intra = jnp.einsum("blm,blmh,bmhp->blhp", g, mexp, xdt)
-            # inter-chunk contribution from the carried state
-            y_inter = jnp.einsum("blh,bln,bhpn->blhp", jnp.exp(cum), ch_c,
-                                 carry)
-            # chunk-local state + recurrence
-            w_local = jnp.exp(total[:, None, :] - cum)  # [B,Lc,H]
-            s_local = jnp.einsum("bmh,bmhp,bmn->bhpn", w_local, xdt, bh_c)
-            new = jnp.exp(total)[:, :, None, None] * carry + s_local
-            return new, (y_intra + y_inter).astype(x.dtype)
-
-        xs = tuple(jnp.moveaxis(t, 1, 0) for t in (xh, bh, ch, dth, logc))
-        final_state, y = jax.lax.scan(body, init, xs)
-    y = jnp.moveaxis(y, 0, 1).reshape(bsz, s, h, hd).astype(jnp.float32)
-    y = y + xh.reshape(bsz, s, h, hd).astype(jnp.float32) * p["d_skip"].astype(jnp.float32)[None, None, :, None]
-    y = y.reshape(bsz, s, din).astype(x.dtype)
-    y = _gated_norm(y, z, p["gate_norm"], cfg.norm_eps)
-    out = jnp.einsum("bsk,kd->bsd", y, p["out_proj"].astype(x.dtype))
+            xs = tuple(jnp.moveaxis(t, 1, 0) for t in (xh, bh, ch, dth, logc))
+            final_state, y = jax.lax.scan(body, init, xs)
+        y = jnp.moveaxis(y, 0, 1).reshape(bsz, s, h, hd).astype(jnp.float32)
+        y = y + xh.reshape(bsz, s, h, hd).astype(jnp.float32) * p["d_skip"].astype(jnp.float32)[None, None, :, None]
+        y = y.reshape(bsz, s, din).astype(x.dtype)
+    with jax.named_scope("mamba2.out"):
+        y = _gated_norm(y, z, p["gate_norm"], cfg.norm_eps)
+        out = jnp.einsum("bsk,kd->bsd", y, p["out_proj"].astype(x.dtype))
     return out, (new_conv, final_state.astype(jnp.float32))
 
 

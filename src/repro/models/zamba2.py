@@ -51,30 +51,38 @@ def _reshape_groups(tree, g, per):
 
 
 def _shared_block(cfg, sp, h, h0, positions, attn_impl, kv_cache=None, cur_len=None):
-    uk = cfg.use_kernels
-    x = jnp.concatenate([h, h0], axis=-1)
-    x = L.rmsnorm(x, sp["ln_in"], cfg.norm_eps, use_kernel=uk)
-    x = jnp.einsum("bse,ed->bsd", x, sp["w_in"].astype(h.dtype))
-    a_in = L.rmsnorm(x, sp["ln1"], cfg.norm_eps, use_kernel=uk)
-    q, k, v = L.qkv_proj(sp["attn"], cfg, a_in, positions)
-    new_kv = None
-    if kv_cache is not None and cur_len is not None:
-        kc, vc = kv_cache
-        idx = cur_len[0]
-        kc = jax.lax.dynamic_update_slice_in_dim(kc, k.astype(kc.dtype), idx, axis=1)
-        vc = jax.lax.dynamic_update_slice_in_dim(vc, v.astype(vc.dtype), idx, axis=1)
-        attn = L.attend_decode(q, kc, vc, cur_len + 1)
-        new_kv = (kc, vc)
-    else:
-        attn = L.attend(q, k, v, positions, positions, True, impl=attn_impl,
-                        use_kernel=uk)
-        if kv_cache == "collect":
-            new_kv = (k, v)
-    x = x + L.out_proj(sp["attn"], attn)
-    x = x + L.mlp(sp["mlp"], cfg,
-                  L.rmsnorm(x, sp["ln2"], cfg.norm_eps, use_kernel=uk))
-    out = jnp.einsum("bsd,de->bse", x, sp["w_out"].astype(h.dtype))
-    return h + out, new_kv
+    """The shared attention+MLP block, under the named scope
+    ``shared_block`` with its own ``norm``/``attn``/``mlp`` inside."""
+    with jax.named_scope("shared_block"):
+        uk = cfg.use_kernels
+        x = jnp.concatenate([h, h0], axis=-1)
+        with jax.named_scope("norm"):
+            x = L.rmsnorm(x, sp["ln_in"], cfg.norm_eps, use_kernel=uk)
+        x = jnp.einsum("bse,ed->bsd", x, sp["w_in"].astype(h.dtype))
+        with jax.named_scope("norm"):
+            a_in = L.rmsnorm(x, sp["ln1"], cfg.norm_eps, use_kernel=uk)
+        with jax.named_scope("attn"):
+            q, k, v = L.qkv_proj(sp["attn"], cfg, a_in, positions)
+            new_kv = None
+            if kv_cache is not None and cur_len is not None:
+                kc, vc = kv_cache
+                idx = cur_len[0]
+                kc = jax.lax.dynamic_update_slice_in_dim(kc, k.astype(kc.dtype), idx, axis=1)
+                vc = jax.lax.dynamic_update_slice_in_dim(vc, v.astype(vc.dtype), idx, axis=1)
+                attn = L.attend_decode(q, kc, vc, cur_len + 1)
+                new_kv = (kc, vc)
+            else:
+                attn = L.attend(q, k, v, positions, positions, True, impl=attn_impl,
+                                use_kernel=uk)
+                if kv_cache == "collect":
+                    new_kv = (k, v)
+            x = x + L.out_proj(sp["attn"], attn)
+        with jax.named_scope("norm"):
+            m_in = L.rmsnorm(x, sp["ln2"], cfg.norm_eps, use_kernel=uk)
+        with jax.named_scope("mlp"):
+            x = x + L.mlp(sp["mlp"], cfg, m_in)
+        out = jnp.einsum("bsd,de->bse", x, sp["w_out"].astype(h.dtype))
+        return h + out, new_kv
 
 
 def forward_hidden(params, cfg: ModelConfig, embeds, positions=None, causal=True,
@@ -88,9 +96,12 @@ def forward_hidden(params, cfg: ModelConfig, embeds, positions=None, causal=True
     mamba = _reshape_groups(params["mamba"], g, per)
 
     def inner(h, p, conv_st, ssm_st):
-        x = L.rmsnorm(h, p["ln"], cfg.norm_eps, use_kernel=cfg.use_kernels)
+        with jax.named_scope("norm"):
+            x = L.rmsnorm(h, p["ln"], cfg.norm_eps,
+                          use_kernel=cfg.use_kernels)
         y, (new_conv, new_ssm) = M.ssd_forward(p["ssd"], cfg, x, conv_st, ssm_st)
-        return h + y, new_conv, new_ssm
+        with jax.named_scope("mamba2.out"):
+            return h + y, new_conv, new_ssm
 
     def outer(h, xs):
         pg = xs
@@ -105,8 +116,9 @@ def forward_hidden(params, cfg: ModelConfig, embeds, positions=None, causal=True
     if remat:
         outer = jax.checkpoint(outer, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
     h, (convs, ssms, kvs) = jax.lax.scan(outer, embeds, mamba)
-    h = L.rmsnorm(h, params["final_norm"], cfg.norm_eps,
-                  use_kernel=cfg.use_kernels)
+    with jax.named_scope("norm"):
+        h = L.rmsnorm(h, params["final_norm"], cfg.norm_eps,
+                      use_kernel=cfg.use_kernels)
 
     aux = None
     if collect_kv:

@@ -10,10 +10,13 @@ schema docs):
 * :class:`MetricsRegistry` (``metrics.py``) — counters / gauges /
   fixed-size-reservoir histograms with stable dotted names and a versioned
   snapshot schema; the single source of truth behind ``stats()``;
+* :func:`fit_offset` (``clock.py``) — the tracer's clock fitted onto a
+  ``jax.profiler`` capture's, from the ``t_ns`` argument of phase spans;
 * exporters + CLI (``export.py`` / ``check.py`` / ``__main__.py``) —
   Chrome trace-event JSON that opens in ui.perfetto.dev, and
   ``python -m repro.obs summarize|diff|check`` over the artifacts.
 """
+from repro.obs.clock import ClockFit, fit_offset, profiler_phases
 from repro.obs.export import (TRACE_SCHEMA, TRACE_VERSION, chrome_trace,
                               load_trace, write_chrome_trace)
 from repro.obs.metrics import (METRICS_SCHEMA, METRICS_VERSION, Counter,
@@ -24,9 +27,10 @@ from repro.obs.trace import (NULL_TRACER, Event, Tracer, is_instrumentation,
                              mark_instrumentation)
 
 __all__ = [
-    "Counter", "Event", "Gauge", "Histogram", "MetricsRegistry",
+    "ClockFit", "Counter", "Event", "Gauge", "Histogram", "MetricsRegistry",
     "METRICS_SCHEMA", "METRICS_VERSION", "NULL_TRACER", "TRACE_SCHEMA",
-    "TRACE_VERSION", "Tracer", "chrome_trace", "format_stats",
+    "TRACE_VERSION", "Tracer", "chrome_trace", "fit_offset", "format_stats",
     "is_instrumentation", "load_snapshot", "load_trace",
-    "mark_instrumentation", "metric_scalar", "write_chrome_trace",
+    "mark_instrumentation", "metric_scalar", "profiler_phases",
+    "write_chrome_trace",
 ]

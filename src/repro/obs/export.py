@@ -18,7 +18,10 @@ directly. Conventions:
   count, free-form run metadata, and (when a registry is passed) the full
   **metrics snapshot** — one artifact holds both the timeline and the
   numbers, which is what lets ``python -m repro.obs check`` verify the
-  serve-timing contracts from a single file.
+  serve-timing contracts from a single file;
+* ``clock_offset_ns`` (a :func:`repro.obs.clock.fit_offset` result) moves
+  every timestamp onto a profiler capture's clock, so the ring opens beside
+  the capture with its spans over their annotations.
 """
 from __future__ import annotations
 
@@ -41,8 +44,10 @@ def _track_ids(track, extra_pids):
 
 
 def chrome_trace(tracer: Tracer, metrics: Optional[MetricsRegistry] = None,
-                 meta: Optional[dict] = None) -> dict:
+                 meta: Optional[dict] = None,
+                 clock_offset_ns: float = 0.0) -> dict:
     """Render the tracer buffer as a Chrome trace-event JSON document."""
+    shift_us = clock_offset_ns * 1e-3
     events = []
     extra_pids: dict = {}
     seen_tracks = {}
@@ -50,7 +55,7 @@ def chrome_trace(tracer: Tracer, metrics: Optional[MetricsRegistry] = None,
         pid, tid = _track_ids(ev.track, extra_pids)
         seen_tracks[(pid, tid)] = ev.track
         rec = {"name": ev.name, "ph": ev.ph, "pid": pid, "tid": tid,
-               "ts": ev.ts * 1e6, "cat": ev.name.split("/")[0]}
+               "ts": ev.ts * 1e6 + shift_us, "cat": ev.name.split("/")[0]}
         if ev.ph == "X":
             rec["dur"] = ev.dur * 1e6
             rec["args"] = ev.args
@@ -80,6 +85,8 @@ def chrome_trace(tracer: Tracer, metrics: Optional[MetricsRegistry] = None,
 
     other = {"schema": TRACE_SCHEMA, "version": TRACE_VERSION,
              "dropped": tracer.dropped, "events": len(tracer.events)}
+    if clock_offset_ns:
+        other["clock_offset_ns"] = clock_offset_ns
     if meta:
         other["meta"] = dict(meta)
     if metrics is not None:
@@ -91,8 +98,10 @@ def chrome_trace(tracer: Tracer, metrics: Optional[MetricsRegistry] = None,
 
 def write_chrome_trace(path: str, tracer: Tracer,
                        metrics: Optional[MetricsRegistry] = None,
-                       meta: Optional[dict] = None) -> dict:
-    doc = chrome_trace(tracer, metrics=metrics, meta=meta)
+                       meta: Optional[dict] = None,
+                       clock_offset_ns: float = 0.0) -> dict:
+    doc = chrome_trace(tracer, metrics=metrics, meta=meta,
+                       clock_offset_ns=clock_offset_ns)
     with open(path, "w") as f:
         json.dump(doc, f, indent=1, sort_keys=True)
     return doc

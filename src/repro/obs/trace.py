@@ -4,7 +4,10 @@ Every event carries **two clocks**: monotonic wall time (``time.monotonic``
 relative to the tracer's birth, exported as Chrome-trace microseconds) and
 the engine's **round-index logical clock** (the ``round`` arg), so timing
 claims can be checked in whichever domain is deterministic — CI contracts
-use rounds, gap analysis uses wall time.
+use rounds, gap analysis uses wall time. :meth:`Tracer.phase` spans are
+also live ``jax.profiler.TraceAnnotation`` s whose ``t_ns`` argument is the
+tracer's reading at entry, which is what fits the tracer's clock onto a
+profiler capture's (:mod:`repro.obs.clock`).
 
 Event taxonomy (the names are the stable API — ``repro.obs`` CLI and the
 tests key on them; see ``src/repro/obs/README.md``):
@@ -14,15 +17,16 @@ tests key on them; see ``src/repro/obs/README.md``):
   ``request/compute`` (admission → accept/evict) on the per-slot track
   (slots are Perfetto tracks; a slot's consecutive residents never
   partially overlap);
-* **per-dispatch device spans** — ``dispatch/round`` / ``dispatch/multi``
-  / ``dispatch/roll`` / ``dispatch/round_keep`` / ``dispatch/admit`` /
-  ``dispatch/migrate`` on the host track (the host is single-threaded, so
-  these are totally ordered), plus ``verify/readback`` for the blocking
-  done-flag readbacks;
+* **engine phases** on the host track (the host is single-threaded, so
+  these are totally ordered and nest): ``serve/step`` around each engine
+  step, holding ``serve/decide``, ``dispatch/admit``, the round dispatches
+  ``dispatch/round`` / ``dispatch/multi`` / ``dispatch/roll`` /
+  ``dispatch/round_keep``, ``verify/readback`` (the blocking done-flag
+  readback) and ``serve/drain``; plus ``dispatch/migrate``;
 * **instants** — ``spec/confirm``, ``spec/rollback``, ``resize/grow``,
   ``resize/shrink``, ``resize/veto``, ``migrate/lanes``, ``preempt``,
   ``deadline/miss``, ``retrace``, ``ckpt/save``, ``ckpt/restore``,
-  ``worker/lost``, ``worker/beat``;
+  ``worker/lost``;
 * **counter tracks** — ``occupancy`` and ``queue_depth`` sampled at each
   dispatch (Chrome ``ph: "C"`` events; render as area tracks in Perfetto).
 
@@ -35,9 +39,10 @@ truncated trace is never mistaken for a quiet run.
 The disabled tracer (``Tracer(enabled=False)``, or the module singleton
 :data:`NULL_TRACER` engines default to) is a **zero-allocation no-op**:
 every recording method returns immediately on the ``enabled`` check,
-``now()`` returns a constant, and span contexts return a shared singleton
-— instrumented code paths are bitwise-neutral relative to un-instrumented
-ones (asserted in ``tests/test_obs.py``).
+``now()`` returns a constant, and :meth:`Tracer.phase` returns a shared
+singleton without building a profiler annotation — instrumented code paths
+are bitwise-neutral relative to un-instrumented ones (asserted in
+``tests/test_obs.py``).
 """
 from __future__ import annotations
 
@@ -74,10 +79,12 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
-class _DispatchSpan:
-    """Context manager emitting one dispatch span on exit; also enters a
-    ``jax.profiler.TraceAnnotation`` so an optional ``jax.profiler.trace``
-    capture aligns device activity with these host spans."""
+class _Phase:
+    """Live host-track span: a ``jax.profiler.TraceAnnotation`` while open,
+    carrying the span's arguments and ``t_ns`` (the tracer's reading at
+    entry, in ns), and one ring span on exit. The clock is read just after
+    the annotation starts, so the two starts differ by as little as the
+    host allows (``repro.obs.clock``)."""
 
     __slots__ = ("_tracer", "_name", "_args", "_round", "_t0", "_ann")
 
@@ -90,18 +97,16 @@ class _DispatchSpan:
         self._ann = None
 
     def __enter__(self):
+        import jax.profiler
+
+        self._ann = jax.profiler.TraceAnnotation(self._name, **self._args)
+        self._ann.__enter__()
         self._t0 = self._tracer.now()
-        try:  # profiler alignment is best-effort: never fail a dispatch
-            import jax.profiler
-            self._ann = jax.profiler.TraceAnnotation(self._name)
-            self._ann.__enter__()
-        except Exception:
-            self._ann = None
+        self._ann.set_metadata(t_ns=int(self._t0 * 1e9))
         return self
 
     def __exit__(self, *exc):
-        if self._ann is not None:
-            self._ann.__exit__(*exc)
+        self._ann.__exit__(*exc)
         self._tracer.span(self._name, self._t0, round_idx=self._round,
                           track=("host", 0), **self._args)
         return False
@@ -162,14 +167,14 @@ class Tracer:
         self._push(Event(name, "C", self.now(), 0.0, track,
                          {"value": float(value)}))
 
-    def dispatch_span(self, name: str, round_idx: Optional[int] = None,
-                      **args):
-        """Context manager for one device-program dispatch: measures the
-        host-side dispatch duration, emits ``dispatch/<name>`` on the host
-        track, and brackets the dispatch in a profiler TraceAnnotation."""
+    def phase(self, name: str, round_idx: Optional[int] = None, **args):
+        """Context manager for one host phase (``serve/step``,
+        ``dispatch/round``, ...): a profiler annotation named ``name`` while
+        open, and a span on the host track when it closes. The disabled
+        tracer returns the shared no-op span and builds nothing."""
         if not self.enabled:
             return _NULL_SPAN
-        return _DispatchSpan(self, f"dispatch/{name}", round_idx, args)
+        return _Phase(self, name, round_idx, args)
 
     def label_track(self, track: Tuple[str, int], label: str) -> None:
         """Optional human label for a track lane (e.g. slot 3 -> "slot 3");

@@ -436,10 +436,8 @@ class ContinuousEngine:
         self._h_gap = m.histogram("serve.round_gap_s")
         m.gauge("serve.overlap").set(float(self.overlap))
         self._last_dispatch_done: Optional[float] = None
-        self._disp_kind: str = "round"
-        self._disp_t0 = 0.0
-        self._disp_args: dict = {}
-        self._disp_ann = None
+        self._disp_phase = None  # the open dispatch/* phase (tracing on)
+        self._disp_live = 0
         self._submit_wall: Dict[int, float] = {}  # rid -> queued-span start
 
     # -- grid management ------------------------------------------------------
@@ -527,11 +525,12 @@ class ContinuousEngine:
                                  migrated=True)
                 self._admit_wall[dst] = t_mig
             self._c_migrations.inc(len(occupied))
-            t0 = self.tracer.now()
-            self.state = self.executor.migrate(old_spec, self.spec)(
-                self.state, old_state, jnp.asarray(mask), jnp.asarray(src))
-            self.tracer.span("dispatch/migrate", t0,
-                             round_idx=self.round_count, lanes=len(occupied))
+            with self.tracer.phase("dispatch/migrate",
+                                   round_idx=self.round_count,
+                                   lanes=len(occupied)):
+                self.state = self.executor.migrate(old_spec, self.spec)(
+                    self.state, old_state, jnp.asarray(mask),
+                    jnp.asarray(src))
             self.tracer.instant("migrate/lanes", round_idx=self.round_count,
                                 lanes=len(occupied), src=old_s, dst=new_s)
         self._c_resizes.inc()
@@ -682,57 +681,58 @@ class ContinuousEngine:
             self.queue.push(item)  # submit round/deadline/credit preserved
         if not dec.admissions:
             return undo
-        mask = np.zeros(self.s, bool)
-        i_arr = np.zeros((self.s, self.k), np.int32)
-        wall = self.tracer.now()
-        hetero = self.lane_profile is not None
-        for a in dec.admissions:
-            mask[a.slot] = True
-            i_arr[a.slot] = a.i_seq
-            self._slot_rtol[a.slot] = a.item.rtol
-            self._slot_item[a.slot] = a.item
-            self._slot_iseq[a.slot] = list(a.i_seq)
-            self._admit_round[a.slot] = now
-            self._admit_wall[a.slot] = wall
-            # the effective mode is the policy's Admission.mode, but only a
-            # lane-profile engine can honor it — a homogeneous grid has no
-            # draft/skip machinery, so everything runs (and is priced) exact
-            mode = a.mode if hetero else "exact"
-            self._slot_mode[a.slot] = mode
-            self._pred_done[a.slot] = self.cost.predict_done_round(
-                a.i_seq, a.item.rtol, now, mode=mode)
-            if record_undo:
-                undo.admissions.append((a.slot, a.item))
-            else:
-                self._trace_admit(a.slot, a.item, now, wall)
-        idx = np.asarray([a.slot for a in dec.admissions], np.int32)
-        kstack = jnp.stack([jnp.asarray(a.item.payload.key)
-                            for a in dec.admissions]).astype(jnp.uint32)
-        keys = jnp.zeros((self.s, 2), jnp.uint32).at[idx].set(kstack)
-        t0 = self.tracer.now()
-        if hetero:
-            # per-slot lane gates derived from the admitted mode: draft
-            # lanes smooth only in "draft"; skipping arms in both non-exact
-            # modes. An "exact" admission zeroes both gates, which makes
-            # every lane-masked select pick the exact operand bitwise.
-            draft_on = np.zeros((self.s,), bool)
-            skip_tau = np.zeros((self.s,), np.float32)
+        # the host-side admission build (mirrors, key and mask arrays, the
+        # eager key stack) and the admit program, as one phase
+        with self.tracer.phase("dispatch/admit", round_idx=now,
+                               lanes=len(dec.admissions)):
+            mask = np.zeros(self.s, bool)
+            i_arr = np.zeros((self.s, self.k), np.int32)
+            wall = self.tracer.now()
+            hetero = self.lane_profile is not None
             for a in dec.admissions:
-                m_eff = self._slot_mode[a.slot]
-                draft_on[a.slot] = m_eff == "draft"
-                skip_tau[a.slot] = (self.lane_skip_tau
-                                    if m_eff in ("draft", "adaptive")
-                                    else 0.0)
-            self.state = self._prog.admit(
-                self.state, jnp.asarray(mask), keys, jnp.asarray(i_arr),
-                jnp.asarray(self._slot_rtol), jnp.asarray(draft_on),
-                jnp.asarray(skip_tau))
-        else:
-            self.state = self._prog.admit(self.state, jnp.asarray(mask),
-                                          keys, jnp.asarray(i_arr),
-                                          jnp.asarray(self._slot_rtol))
-        self.tracer.span("dispatch/admit", t0, round_idx=now,
-                         lanes=len(dec.admissions))
+                mask[a.slot] = True
+                i_arr[a.slot] = a.i_seq
+                self._slot_rtol[a.slot] = a.item.rtol
+                self._slot_item[a.slot] = a.item
+                self._slot_iseq[a.slot] = list(a.i_seq)
+                self._admit_round[a.slot] = now
+                self._admit_wall[a.slot] = wall
+                # the effective mode is the policy's Admission.mode, but only a
+                # lane-profile engine can honor it — a homogeneous grid has no
+                # draft/skip machinery, so everything runs (and is priced) exact
+                mode = a.mode if hetero else "exact"
+                self._slot_mode[a.slot] = mode
+                self._pred_done[a.slot] = self.cost.predict_done_round(
+                    a.i_seq, a.item.rtol, now, mode=mode)
+                if record_undo:
+                    undo.admissions.append((a.slot, a.item))
+                else:
+                    self._trace_admit(a.slot, a.item, now, wall)
+            idx = np.asarray([a.slot for a in dec.admissions], np.int32)
+            kstack = jnp.stack([jnp.asarray(a.item.payload.key)
+                                for a in dec.admissions]).astype(jnp.uint32)
+            keys = jnp.zeros((self.s, 2), jnp.uint32).at[idx].set(kstack)
+            if hetero:
+                # per-slot lane gates derived from the admitted mode: draft
+                # lanes smooth only in "draft"; skipping arms in both non-exact
+                # modes. An "exact" admission zeroes both gates, which makes
+                # every lane-masked select pick the exact operand bitwise.
+                draft_on = np.zeros((self.s,), bool)
+                skip_tau = np.zeros((self.s,), np.float32)
+                for a in dec.admissions:
+                    m_eff = self._slot_mode[a.slot]
+                    draft_on[a.slot] = m_eff == "draft"
+                    skip_tau[a.slot] = (self.lane_skip_tau
+                                        if m_eff in ("draft", "adaptive")
+                                        else 0.0)
+                self.state = self._prog.admit(
+                    self.state, jnp.asarray(mask), keys, jnp.asarray(i_arr),
+                    jnp.asarray(self._slot_rtol), jnp.asarray(draft_on),
+                    jnp.asarray(skip_tau))
+            else:
+                self.state = self._prog.admit(self.state, jnp.asarray(mask),
+                                              keys, jnp.asarray(i_arr),
+                                              jnp.asarray(self._slot_rtol))
         return undo
 
     # -- commit-point trace emission ------------------------------------------
@@ -835,18 +835,13 @@ class ContinuousEngine:
         if self.tracer.enabled:
             # each dispatch span carries its own measured busy-grid gap, so
             # the round-gap contract is checkable from the trace alone
-            self._disp_kind = kind
-            self._disp_args = {"rounds": int(rounds), "live": int(live)}
+            args = {"rounds": int(rounds), "live": int(live)}
             if g is not None:
-                self._disp_args["gap_s"] = g
-            self._disp_t0 = self.tracer.now()
-            try:  # profiler alignment is best-effort: never fail a dispatch
-                import jax.profiler
-                self._disp_ann = jax.profiler.TraceAnnotation(
-                    f"dispatch/{kind}")
-                self._disp_ann.__enter__()
-            except Exception:
-                self._disp_ann = None
+                args["gap_s"] = g
+            self._disp_live = int(live)
+            self._disp_phase = self.tracer.phase(
+                f"dispatch/{kind}", round_idx=self.round_count, **args)
+            self._disp_phase.__enter__()
 
     def _dispatch_done(self):
         """Called immediately AFTER the dispatch call returns (jax dispatch
@@ -854,12 +849,9 @@ class ContinuousEngine:
         moment the device stops needing the host)."""
         self._last_dispatch_done = time.monotonic()
         if self.tracer.enabled:
-            if self._disp_ann is not None:
-                self._disp_ann.__exit__(None, None, None)
-                self._disp_ann = None
-            self.tracer.span(f"dispatch/{self._disp_kind}", self._disp_t0,
-                             round_idx=self.round_count, **self._disp_args)
-            self.tracer.counter("occupancy", self._disp_args.get("live", 0))
+            self._disp_phase.__exit__(None, None, None)
+            self._disp_phase = None
+            self.tracer.counter("occupancy", self._disp_live)
             self.tracer.counter("queue_depth", len(self.queue))
 
     # -- shared step pieces ---------------------------------------------------
@@ -962,21 +954,30 @@ class ContinuousEngine:
         next round is made from predicted lane state while the previous
         round is still in flight, and the done-flag readback happens only
         when the cost model says a lane is due to finish.
+
+        Traced, each step is a ``serve/step`` phase holding ``serve/decide``
+        (resize check, lane views, policy decision), ``dispatch/admit``,
+        the round dispatch, ``verify/readback`` and ``serve/drain``.
         """
-        if self.overlap:
-            return self._step_overlap(max_rounds_on_device)
-        return self._step_sync(max_rounds_on_device)
+        with self.tracer.phase("serve/step", round_idx=self.round_count):
+            if self.overlap:
+                return self._step_overlap(max_rounds_on_device)
+            return self._step_sync(max_rounds_on_device)
 
     def _step_sync(self, max_rounds_on_device: int = 1
                    ) -> list[tuple[int, SampleOut]]:
-        self._maybe_resize()
-        free = [i for i, it in enumerate(self._slot_item) if it is None]
-        if len(self.queue) and (free or self.policy.preemptive):
-            view = EngineView(now=self.round_count, queue=self.queue,
-                              free_slots=free, lanes=self._lane_views(),
-                              cost=self.cost,
-                              lane_modes=self.lane_profile is not None)
-            self._apply_decision(self.policy.decide(view))
+        dec = None
+        with self.tracer.phase("serve/decide", round_idx=self.round_count):
+            self._maybe_resize()
+            free = [i for i, it in enumerate(self._slot_item) if it is None]
+            if len(self.queue) and (free or self.policy.preemptive):
+                view = EngineView(now=self.round_count, queue=self.queue,
+                                  free_slots=free, lanes=self._lane_views(),
+                                  cost=self.cost,
+                                  lane_modes=self.lane_profile is not None)
+                dec = self.policy.decide(view)
+        if dec is not None:
+            self._apply_decision(dec)
         if not self.has_inflight:
             # a fully idle grid is the lowest occupancy there is: idle
             # steps count toward the shrink hysteresis so a drained engine
@@ -994,20 +995,21 @@ class ContinuousEngine:
                                            jnp.asarray(r_dev, jnp.int32))
             self._dispatch_done()
             self.state = st
-            t0 = self.tracer.now()
-            ran, done, rounds_used, chosen = jax.device_get(
-                (ran_dev, st.done, st.rounds_used, st.chosen))
+            with self.tracer.phase("verify/readback",
+                                   round_idx=self.round_count, live=live_ct):
+                ran, done, rounds_used, chosen = jax.device_get(
+                    (ran_dev, st.done, st.rounds_used, st.chosen))
             ran = int(ran)
         else:
             self._mark_dispatch("round", live=live_ct)
             self.state = self._prog.round(self.state)
             self._dispatch_done()
-            t0 = self.tracer.now()
-            done, rounds_used, chosen = jax.device_get(
-                (self.state.done, self.state.rounds_used, self.state.chosen))
+            with self.tracer.phase("verify/readback",
+                                   round_idx=self.round_count, live=live_ct):
+                done, rounds_used, chosen = jax.device_get(
+                    (self.state.done, self.state.rounds_used,
+                     self.state.chosen))
             ran = 1
-        self.tracer.span("verify/readback", t0, round_idx=self.round_count,
-                         live=live_ct)
         self._c_host_syncs.inc()
         self.round_count += ran
         self._c_live.inc(live_ct * ran)
@@ -1017,19 +1019,33 @@ class ContinuousEngine:
         out: list[tuple[int, SampleOut]] = []
         drain = [slot for slot in range(self.s)
                  if self._slot_item[slot] is not None and done[slot]]
+        if drain:
+            with self.tracer.phase("serve/drain", round_idx=self.round_count,
+                                   lanes=len(drain)):
+                out = self._drain_sync(drain, rounds_used, chosen)
+
+        live_after = sum(it is not None for it in self._slot_item)
+        self._update_streak(live_ct, live_after, ran)
+        if not self.has_inflight:
+            self._last_dispatch_done = None
+        return out
+
+    def _drain_sync(self, drain, rounds_used, chosen
+                    ) -> list[tuple[int, SampleOut]]:
+        """Gather, transfer and account the synchronous engine's finished
+        lanes ``drain``, freeing their slots."""
         # one gather + one transfer for the whole drain set — a per-slot
         # device_get here was an extra host sync per finished request
         # (caught by the repro.analysis triage); the lane skip counters
         # ride the same transfer on a heterogeneous grid
-        results, drain_skips = [], None
-        if drain:
-            d_idx = np.asarray(drain)
-            if self.lane_profile is not None:
-                results, drain_skips = jax.device_get(
-                    (self.state.result[d_idx],
-                     self.state.lanes.skips[d_idx]))
-            else:
-                results = jax.device_get(self.state.result[d_idx])
+        d_idx = np.asarray(drain)
+        drain_skips = None
+        if self.lane_profile is not None:
+            results, drain_skips = jax.device_get(
+                (self.state.result[d_idx], self.state.lanes.skips[d_idx]))
+        else:
+            results = jax.device_get(self.state.result[d_idx])
+        out = []
         for j, slot in enumerate(drain):
             item = self._slot_item[slot]
             out.append(self._finish_lane(
@@ -1042,11 +1058,6 @@ class ContinuousEngine:
             self._slot_item[slot] = None  # slot is free; done flag stays
             self._pred_done[slot] = None  # until the next admission clears
             # it (the lane is frozen)
-
-        live_after = sum(it is not None for it in self._slot_item)
-        self._update_streak(live_ct, live_after, ran)
-        if not self.has_inflight:
-            self._last_dispatch_done = None
         return out
 
     # -- async double-buffered host loop --------------------------------------
@@ -1082,20 +1093,36 @@ class ContinuousEngine:
         identical numbers to the synchronous engine, independent of when
         the host discovered the accept.
         """
-        self._maybe_resize()
-        now = self.round_count
-        occupied = [i for i, it in enumerate(self._slot_item)
-                    if it is not None]
-        free = [i for i, it in enumerate(self._slot_item) if it is None]
-        due = [s for s in occupied if self._pred_done[s] is None
-               or self._pred_done[s] <= now]
-        if not occupied and not len(self.queue):
+        with self.tracer.phase("serve/decide", round_idx=self.round_count):
+            self._maybe_resize()
+            now = self.round_count
+            occupied = [i for i, it in enumerate(self._slot_item)
+                        if it is not None]
+            free = [i for i, it in enumerate(self._slot_item) if it is None]
+            due = [s for s in occupied if self._pred_done[s] is None
+                   or self._pred_done[s] <= now]
+            idle = not occupied and not len(self.queue)
+            want_decide = bool(len(self.queue)) and \
+                bool(free or due or self.policy.preemptive)
+            dec = Decision()
+            if want_decide:
+                view = EngineView(
+                    now=now, queue=self.queue,
+                    # predicted post-drain state: due lanes presumed
+                    # finished. sorted() matches the ascending slot order
+                    # the synchronous engine's free list has at the
+                    # equivalent step
+                    free_slots=sorted(free + due),
+                    lanes=[ln for ln in self._lane_views()
+                           if ln.slot not in due],
+                    cost=self.cost, speculative=bool(due),
+                    lane_modes=self.lane_profile is not None)
+                dec = self.policy.decide(view)  # pops the admitted items
+        if idle:
             if self.min_slots != self.max_slots:
                 self._low_streak += 1
             self._last_dispatch_done = None
             return []
-        want_decide = bool(len(self.queue)) and \
-            bool(free or due or self.policy.preemptive)
 
         if not due and not want_decide and occupied:
             # fast path: nothing can finish and nothing to decide — roll up
@@ -1123,32 +1150,20 @@ class ContinuousEngine:
         # -- event step: speculate + dispatch ahead of the verify ----------
         need_verify = bool(due)
         prev = self.state
-        # drain metadata BEFORE the decision may overwrite it (a confirmed
-        # speculative admit re-targets the due slot in the same step)
+        # drain metadata BEFORE applying the decision may overwrite it (a
+        # confirmed speculative admit re-targets the due slot in the same
+        # step)
         due_meta = {s: (self._slot_item[s], self._slot_iseq[s],
                         self._admit_round[s], self._admit_wall[s],
                         self._slot_mode[s])
                     for s in due}
-        dec, undo, spec_admits = Decision(), None, []
-        if want_decide:
-            view = EngineView(
-                now=now, queue=self.queue,
-                # predicted post-drain state: due lanes presumed finished.
-                # sorted() matches the ascending slot order the synchronous
-                # engine's free list has at the equivalent step
-                free_slots=sorted(free + due),
-                lanes=[ln for ln in self._lane_views()
-                       if ln.slot not in due_meta],
-                cost=self.cost, speculative=need_verify,
-                lane_modes=self.lane_profile is not None)
-            dec = self.policy.decide(view)
-            spec_admits = [a.slot for a in dec.admissions
-                           if a.slot in due_meta]
-            if dec.admissions or dec.evictions:
-                undo = self._apply_decision(dec, now=now,
-                                            record_undo=need_verify)
-                if spec_admits:
-                    self._c_spec.inc()
+        undo = None
+        spec_admits = [a.slot for a in dec.admissions if a.slot in due_meta]
+        if dec.admissions or dec.evictions:
+            undo = self._apply_decision(dec, now=now,
+                                        record_undo=need_verify)
+            if spec_admits:
+                self._c_spec.inc()
         # lanes presumed still running after the presumed drains: skip the
         # dispatch entirely when the grid would be empty (the synchronous
         # engine does not run a round on its final drain either)
@@ -1167,20 +1182,19 @@ class ContinuousEngine:
         if need_verify:
             # ONE blocking readback per event step — the flags (and the due
             # results) of the round that finished while we were speculating
-            t0 = self.tracer.now()
             due_idx = np.asarray(due, np.int32)
-            if self.lane_profile is not None:
-                done, rounds_used, chosen, due_res, due_skips = \
-                    jax.device_get(
+            with self.tracer.phase("verify/readback", round_idx=now,
+                                   due=len(due)):
+                if self.lane_profile is not None:
+                    done, rounds_used, chosen, due_res, due_skips = \
+                        jax.device_get(
+                            (prev.done, prev.rounds_used, prev.chosen,
+                             prev.result[due_idx], prev.lanes.skips[due_idx]))
+                else:
+                    done, rounds_used, chosen, due_res = jax.device_get(
                         (prev.done, prev.rounds_used, prev.chosen,
-                         prev.result[due_idx], prev.lanes.skips[due_idx]))
-            else:
-                done, rounds_used, chosen, due_res = jax.device_get(
-                    (prev.done, prev.rounds_used, prev.chosen,
-                     prev.result[due_idx]))
-                due_skips = None
-            self.tracer.span("verify/readback", t0, round_idx=now,
-                             due=len(due))
+                         prev.result[due_idx]))
+                    due_skips = None
             self._c_host_syncs.inc()
             failed = [s for s in spec_admits if not done[s]]
             if failed:
@@ -1203,13 +1217,15 @@ class ContinuousEngine:
                 free2 = [i for i, it in enumerate(self._slot_item)
                          if it is None]
                 if len(self.queue) and (free2 or self.policy.preemptive):
-                    view = EngineView(now=now, queue=self.queue,
-                                      free_slots=free2,
-                                      lanes=self._lane_views(),
-                                      cost=self.cost,
-                                      lane_modes=self.lane_profile
-                                      is not None)
-                    self._apply_decision(self.policy.decide(view), now=now)
+                    with self.tracer.phase("serve/decide", round_idx=now):
+                        view = EngineView(now=now, queue=self.queue,
+                                          free_slots=free2,
+                                          lanes=self._lane_views(),
+                                          cost=self.cost,
+                                          lane_modes=self.lane_profile
+                                          is not None)
+                        redo = self.policy.decide(view)
+                    self._apply_decision(redo, now=now)
                 if any(it is not None for it in self._slot_item):
                     self._mark_dispatch("round", live=sum(
                         it is not None for it in self._slot_item))
@@ -1261,23 +1277,25 @@ class ContinuousEngine:
         confirmed already carries its NEW item in the mirrors — the old
         lane's identity (and lane mode) comes from ``due_meta`` and the
         slot is not freed."""
-        out = []
-        for j, s in enumerate(due):
-            item, i_seq, admit_round, admit_wall, mode = due_meta[s]
-            if not done[s]:
-                continue
-            ru = int(rounds_used[s])
-            out.append(self._finish_lane(item, i_seq, ru, int(chosen[s]),
-                                         due_res[j],
-                                         acc_round=admit_round + ru,
-                                         slot=s, admit_wall=admit_wall,
-                                         mode=mode,
-                                         skips=int(due_skips[j].sum())
-                                         if due_skips is not None else 0))
-            if self._slot_item[s] is item:
-                self._slot_item[s] = None  # freed; stale flags stay until
-                self._pred_done[s] = None  # the next admission (frozen lane)
-        return out
+        with self.tracer.phase("serve/drain", round_idx=self.round_count,
+                               lanes=len(due)):
+            out = []
+            for j, s in enumerate(due):
+                item, i_seq, admit_round, admit_wall, mode = due_meta[s]
+                if not done[s]:
+                    continue
+                ru = int(rounds_used[s])
+                out.append(self._finish_lane(item, i_seq, ru, int(chosen[s]),
+                                             due_res[j],
+                                             acc_round=admit_round + ru,
+                                             slot=s, admit_wall=admit_wall,
+                                             mode=mode,
+                                             skips=int(due_skips[j].sum())
+                                             if due_skips is not None else 0))
+                if self._slot_item[s] is item:
+                    self._slot_item[s] = None  # freed; stale flags stay until
+                    self._pred_done[s] = None  # the next admission (frozen lane)
+            return out
 
     def run_until_drained(self, max_rounds: Optional[int] = None,
                           max_rounds_on_device: int = 1

@@ -258,10 +258,13 @@ def _grid_fns(drift, tgrid, n: int, spec: GridSpec,
     hetero = spec.lane_profile is not None
     apply, _ = split_drift(drift)
 
+    @functools.partial(_scoped, "chords.step")
     def round_fn(params, st: SlotState) -> SlotState:
-        """One lockstep round for every live slot + per-slot accept test."""
+        """One lockstep round for every live slot + per-slot accept test.
+        The round runs under the named scope ``chords.step`` and its drift
+        under ``drift`` (``repro.obs.scopes``)."""
         slot_round = make_slot_round_body(
-            functools.partial(apply, params), tgrid, n, k,
+            _scoped("drift", functools.partial(apply, params)), tgrid, n, k,
             use_kernel=use_kernel, fuse_accept=fuse_accept,
             lane_profile=spec.lane_profile)
         active = st.live

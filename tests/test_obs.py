@@ -170,12 +170,12 @@ def test_null_tracer_records_nothing():
     t.instant("spec/rollback", round_idx=3)
     t.span("request/compute", 0.0, round_idx=1)
     t.counter("occupancy", 1.0)
-    with t.dispatch_span("round", round_idx=0):
+    with t.phase("dispatch/round", round_idx=0):
         pass
     t.label_track(("slots", 0), "slot 0")
     assert len(t) == 0 and t.dropped == 0 and t.track_labels == {}
     # and the same context-manager singleton is reused (zero allocation)
-    assert t.dispatch_span("round") is t.dispatch_span("admit")
+    assert t.phase("dispatch/round") is t.phase("dispatch/admit")
 
 
 # -- bounded buffers ----------------------------------------------------------
