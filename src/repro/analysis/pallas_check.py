@@ -15,8 +15,9 @@ this pass can concretely enumerate the grid and evaluate every
                         on GPU/interpret it is parallel, so overlapping
                         writes are nondeterministic (error).
 * ``vmem``            — per-program footprint (all input+output blocks,
-                        x2 for double buffering) over the VMEM budget
-                        (error), or over half of it (info).
+                        x2 for double buffering, plus the launch's scratch
+                        once) over the VMEM budget (error), or over half of
+                        it (info).
 * ``oracle-mismatch`` — kernel op and its ``ref.py`` oracle disagree on
                         abstract output shapes/dtypes (error).
 
@@ -29,30 +30,18 @@ from __future__ import annotations
 import itertools
 from typing import Iterable, List, Sequence, Tuple
 
-import numpy as np
-
 from repro.analysis.report import Finding
-from repro.kernels.meta import BlockMeta, KernelLaunch
+from repro.kernels.meta import BlockMeta, KernelLaunch, vmem_bytes
 
 PASS = "pallas"
 
 VMEM_BUDGET_BYTES = 16 * 1024 * 1024  # per-core VMEM on current TPUs
-DOUBLE_BUFFER = 2  # pipelined pallas_call keeps two copies of each block
 
 Region = Tuple[Tuple[int, int], ...]  # ((origin, extent), ...) per array dim
 
 
 def grid_points(grid: Sequence[int]) -> List[Tuple[int, ...]]:
     return list(itertools.product(*(range(g) for g in grid)))
-
-
-def block_extents(meta: BlockMeta) -> Tuple[int, ...]:
-    return tuple(1 if b is None else int(b) for b in meta.block_shape)
-
-
-def block_bytes(meta: BlockMeta) -> int:
-    return int(np.prod(block_extents(meta), dtype=np.int64)
-               * np.dtype(meta.dtype).itemsize)
 
 
 def region(meta: BlockMeta, idx: Tuple[int, ...]) -> Region:
@@ -105,11 +94,9 @@ def check_launch(launch: KernelLaunch,
     findings: List[Finding] = []
     points = grid_points(launch.grid)
 
-    vmem = 0
     for role, metas in (("in", launch.inputs), ("out", launch.outputs)):
         for meta in metas:
             loc = f"{launch.kernel}:{meta.name}"
-            vmem += block_bytes(meta)
 
             # arity: index_map must accept exactly one index per grid dim
             try:
@@ -150,20 +137,19 @@ def check_launch(launch: KernelLaunch,
                         f"overlapping output blocks, e.g. {pa} vs {pb} — "
                         f"nondeterministic on parallel backends"))
 
-    vmem *= DOUBLE_BUFFER
+    vmem = vmem_bytes(launch)
     vloc = f"{launch.kernel}:grid{tuple(launch.grid)}"
     if vmem > vmem_budget_bytes:
         findings.append(Finding(
             PASS, "vmem", "error", vloc,
-            f"{vloc}: per-program footprint {vmem} B (double-buffered) "
-            f"exceeds the {vmem_budget_bytes} B VMEM budget — shrink the "
-            f"block shapes"))
+            f"{vloc}: per-program footprint {vmem} B (double-buffered "
+            f"blocks and scratch) exceeds the {vmem_budget_bytes} B VMEM "
+            f"budget — shrink the block shapes"))
     elif vmem > vmem_budget_bytes // 2:
         findings.append(Finding(
             PASS, "vmem", "info", vloc,
             f"{vloc}: per-program footprint {vmem} B is over half the "
-            f"{vmem_budget_bytes} B VMEM budget; headroom for scratch is "
-            f"thin"))
+            f"{vmem_budget_bytes} B VMEM budget; headroom is thin"))
     return findings
 
 

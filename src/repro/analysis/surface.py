@@ -126,10 +126,10 @@ def kernel_cases() -> List[KernelCase]:
     f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)
     cases = []
 
-    b, sq, h, dh, sk, kvh, bq, bk = 2, 256, 4, 64, 256, 2, 128, 128
+    b, sq, h, dh, sk, kvh = 2, 256, 4, 64, 256, 2  # default (shape) tiles
     cases.append(KernelCase(
-        "flash_attention", flash_meta(b, sq, h, dh, sk, kvh, bq, bk),
-        functools.partial(flash_attention, causal=True, bq=bq, bk=bk),
+        "flash_attention", flash_meta(b, sq, h, dh, sk, kvh),
+        functools.partial(flash_attention, causal=True),
         functools.partial(attention_ref, causal=True),
         (f32(b, sq, h, dh), f32(b, sk, kvh, dh), f32(b, sk, kvh, dh)),
         (f32(b, sq, h, dh), f32(b, sk, kvh, dh), f32(b, sk, kvh, dh))))

@@ -12,7 +12,12 @@ kernel cannot silently change its tiling out from under the analysis.
 """
 from __future__ import annotations
 
+import math
 from typing import Callable, NamedTuple, Optional, Tuple
+
+import numpy as np
+
+DOUBLE_BUFFER = 2  # a pipelined pallas_call keeps two copies of each block
 
 
 class BlockMeta(NamedTuple):
@@ -32,6 +37,16 @@ class BlockMeta(NamedTuple):
     dtype: str
 
 
+class ScratchMeta(NamedTuple):
+    """A block-shaped buffer one program keeps in VMEM besides its operand
+    blocks (a score tile, running statistics, an accumulator): held once per
+    program, not double-buffered."""
+
+    name: str
+    shape: Tuple[int, ...]
+    dtype: str
+
+
 class KernelLaunch(NamedTuple):
     """A kernel's complete static launch description."""
 
@@ -39,6 +54,18 @@ class KernelLaunch(NamedTuple):
     grid: Tuple[int, ...]
     inputs: Tuple[BlockMeta, ...]
     outputs: Tuple[BlockMeta, ...]
+    scratch: Tuple[ScratchMeta, ...] = ()
+
+
+def vmem_bytes(launch: KernelLaunch) -> int:
+    """One program's static VMEM footprint: every operand block
+    double-buffered, plus the scratch once."""
+    blocks = sum(math.prod(1 if b is None else b for b in m.block_shape)
+                 * np.dtype(m.dtype).itemsize
+                 for m in launch.inputs + launch.outputs)
+    scratch = sum(math.prod(m.shape) * np.dtype(m.dtype).itemsize
+                  for m in launch.scratch)
+    return DOUBLE_BUFFER * blocks + scratch
 
 
 def block_specs(metas):

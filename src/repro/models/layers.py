@@ -260,7 +260,8 @@ def attend_decode(q, k_cache, v_cache, cur_len, scale: Optional[float] = None):
 
 def flash_kernel_compatible(q, k) -> bool:
     """Whether the Pallas flash kernel's tiling accepts these shapes:
-    Sq/Sk must divide into their (<=128) tiles. The kernel additionally
+    Sq/Sk must each be a multiple of 128 or shorter than that (the kernel
+    then picks its tiles from 512 / 256 / 128). The kernel additionally
     assumes positions are 0-based aranges (it derives the causal mask from
     tile indices) — true for every backbone path that enables kernels."""
     sq, sk = q.shape[1], k.shape[1]
@@ -276,10 +277,10 @@ def attend(q, k, v, q_pos, k_pos, causal: bool, impl: str = "auto",
     requires 0-based arange positions (what ``forward_hidden`` passes) and
     tile-divisible sequence lengths; a kernel request the flash kernel
     cannot serve raises instead of quietly running the jnp path.
-    Kernel-vs-jnp parity is tolerance-level, not bitwise: ``attend_full``
-    scales logits after the QK matmul while the flash kernel (like
-    ``attention_ref``) scales q first, and the online softmax reassociates
-    the reduction (see kernels/README.md).
+    Kernel-vs-jnp parity is tolerance-level, not bitwise: the flash kernel
+    feeds its dots the operands' dtype (rounding the probabilities to it
+    before the PV dot) and its online softmax reassociates the reduction
+    (see kernels/README.md).
     """
     mode = resolve_kernel_mode(use_kernel)
     if mode is not None:

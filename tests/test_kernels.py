@@ -5,7 +5,7 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.kernels.flash_attention.kernel import flash_attention
+from repro.kernels.flash_attention.kernel import flash_attention, pick_tile
 from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.rectify.kernel import fused_step_rectify
 from repro.kernels.rectify.ref import fused_step_rectify_ref
@@ -30,22 +30,32 @@ def test_rectify_kernel_sweep(k, m, dtype):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
 
 
-@pytest.mark.parametrize("sq,sk,h,kv,dh,causal,dtype", [
-    (128, 128, 4, 4, 32, True, jnp.float32),
-    (128, 128, 4, 2, 32, True, jnp.float32),   # GQA
-    (64, 256, 8, 1, 64, False, jnp.float32),   # MQA, cross
-    (256, 256, 2, 2, 64, True, jnp.bfloat16),
+@pytest.mark.parametrize("sq,sk,h,kv,dh,causal,dtype,bq,bk", [
+    (128, 128, 4, 4, 32, True, jnp.float32, 64, 64),
+    (128, 128, 4, 2, 32, True, jnp.float32, 64, 64),   # GQA
+    (64, 256, 8, 1, 64, False, jnp.float32, 64, 64),   # MQA, cross
+    (256, 256, 2, 2, 64, True, jnp.bfloat16, 64, 64),
+    (1024, 1024, 2, 2, 128, False, jnp.bfloat16, None, None),  # 512 tiles
+    (512, 512, 2, 2, 64, True, jnp.float32, 128, 256),  # causal, bq < bk
+    (512, 512, 2, 2, 64, True, jnp.float32, 256, 128),  # causal, bq > bk
+    (256, 256, 4, 4, 80, True, jnp.bfloat16, None, None),  # Zamba2 heads
 ])
-def test_flash_attention_sweep(sq, sk, h, kv, dh, causal, dtype):
+def test_flash_attention_sweep(sq, sk, h, kv, dh, causal, dtype, bq, bk):
     ks = jax.random.split(KEY, 3)
     q = jax.random.normal(ks[0], (2, sq, h, dh), dtype)
     k = jax.random.normal(ks[1], (2, sk, kv, dh), dtype)
     v = jax.random.normal(ks[2], (2, sk, kv, dh), dtype)
-    out = flash_attention(q, k, v, causal=causal, bq=64, bk=64)
+    out = flash_attention(q, k, v, causal=causal, bq=bq, bk=bk)
     ref = attention_ref(q, k, v, causal)
     atol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), atol=atol)
+
+
+@pytest.mark.parametrize("n,tile", [(4096, 512), (768, 256), (384, 128),
+                                    (64, 64)])
+def test_flash_attention_default_tile(n, tile):
+    assert pick_tile(n) == tile
 
 
 @pytest.mark.parametrize("rows,d,dtype", [

@@ -18,6 +18,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 B, SEQ, HEADS, HEAD_DIM, D_MODEL = 8, 4096, 24, 128, 3072  # chords-dit-xl
+Z_HEADS, Z_HEAD_DIM = 32, 80  # zamba2-2.7b's shared-block attention
 K, LATENT = 8, 4096 * 64  # cores x one (4096, 64) latent
 
 
@@ -50,6 +51,21 @@ def test_flash_attention_compiles(one_chip):
     qkv = ((B, SEQ, HEADS, HEAD_DIM), jnp.bfloat16)
     text = _compiled_text(
         functools.partial(flash_attention, causal=False, interpret=False),
+        qkv, qkv, qkv, sharding=one_chip)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("heads,head_dim,dtype,causal", [
+    (Z_HEADS, Z_HEAD_DIM, jnp.bfloat16, True),  # lanes sliced, two loops
+    (HEADS, HEAD_DIM, jnp.float32, False),  # raises the scoped VMEM limit
+], ids=["zamba2-causal-bf16", "dit-f32"])
+def test_flash_attention_compiles_default_tiles(one_chip, heads, head_dim,
+                                                dtype, causal):
+    from repro.kernels.flash_attention.kernel import flash_attention
+
+    qkv = ((B, SEQ, heads, head_dim), dtype)
+    text = _compiled_text(
+        functools.partial(flash_attention, causal=causal, interpret=False),
         qkv, qkv, qkv, sharding=one_chip)
     assert "tpu_custom_call" in text
 
