@@ -58,7 +58,7 @@ def control_readings(cell, seed, record, rehearse):
     structure = jax.eval_shape(
         lambda k: init_wrapper(cfg, latent_shape[-1], k, cfg.param_dtype),
         jax.random.PRNGKey(0))
-    params = weights.draw(structure, wseed)
+    params = weights.draw(structure, wseed, model["family"])
     keys = np.asarray(jax.random.split(jax.random.PRNGKey(kseed),
                                        bench_run.N_KEYS))
     i_seq = record["run"]["i_seq"]

@@ -12,6 +12,8 @@ are each operand and output read or written once (what a pipelined
 """
 from __future__ import annotations
 
+import backbones
+
 
 def _attention(s: int, heads: int, head_dim: int, causal: bool) -> float:
     pairs = s * (s + 1) / 2 if causal else s * s
@@ -29,12 +31,6 @@ def _attn_block(s: int, m: dict, causal: bool) -> float:
     return proj + _attention(s, h, dh, causal)
 
 
-def dense_backbone(s: int, m: dict) -> float:
-    per_layer = _attn_block(s, m, causal=False) + _mlp(s, m["d_model"],
-                                                       m["d_ff"])
-    return m["num_layers"] * per_layer
-
-
 def mamba2_layer(s: int, m: dict) -> float:
     d, n = m["d_model"], m["ssm_state"]
     din = m["ssm_expand"] * d
@@ -48,25 +44,13 @@ def mamba2_layer(s: int, m: dict) -> float:
     return in_proj + conv + scan + out_proj
 
 
-def hybrid_backbone(s: int, m: dict) -> float:
-    d = m["d_model"]
-    calls = m["num_layers"] // m["attn_every"]
-    shared = (2 * s * 2 * d * d                    # w_in on concat(h, h0)
-              + _attn_block(s, m, causal=True)
-              + _mlp(s, d, m["d_ff"])
-              + 2 * s * d * d)                     # w_out
-    return m["num_layers"] * mamba2_layer(s, m) + calls * shared
-
-
-BACKBONES = {"dense": dense_backbone, "hybrid": hybrid_backbone}
-
-
 def drift_forward(m: dict, seq: int, latent_dim: int) -> float:
     """FLOPs of one drift evaluation of one latent of ``seq`` tokens: the
-    wrapper's in/out projections and time MLP plus the backbone."""
+    wrapper's in/out projections and time MLP plus the backbone, whose
+    count is its family's (``bench/backbones/<family>.py``)."""
     d = m["d_model"]
     wrapper = 2 * seq * latent_dim * d * 2 + 2 * 256 * d + 2 * d * d
-    return wrapper + BACKBONES[m["family"]](seq, m)
+    return wrapper + backbones.load(m["family"]).flops(seq, m)
 
 
 def flash_attention_call(batch: int, heads: int, sq: int, sk: int,

@@ -4,10 +4,14 @@
     JAX_PLATFORMS=cpu python3 bench/flops_check.py
 
 Compiles, for a described v5e (``v5e:2x2``, one chip), one drift forward
-of each configuration at the cells' batch (S x K = 8 latents of 4096x64,
-the backbone on its jnp path) and the two kernels at the cells' shapes,
-and prints ``compiled.cost_analysis()``'s flops and bytes accessed beside
-the analytic ones, with their ratio. Nothing runs, so there are no times.
+of each configuration in ``BENCHMARK.json`` at the cells' batch (S x K = 8
+latents of 4096x64, the backbone on its jnp path) and the two kernels at
+the cells' shapes, and prints ``compiled.cost_analysis()``'s flops and
+bytes accessed beside the analytic ones, with their ratio. Nothing runs,
+so there are no times. The analytic count of a drift forward is
+``flops.drift_forward``: the wrapper's, plus the backbone's from its
+family's file, ``bench/backbones/<family>.py``, where a new family's count
+lives.
 """
 from __future__ import annotations
 
@@ -53,9 +57,12 @@ def main():
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        entries = json.load(f)["configs"]
     rows = []
-    for name in ("chords-dit-xl-2l", "zamba2-2.7b-1p"):
-        with open(os.path.join(BENCH, "configs", name + ".json")) as f:
+    for entry in entries:
+        name = entry["name"]
+        with open(os.path.join(ROOT, entry["file"])) as f:
             conf = json.load(f)
         cfg = get_config(conf["arch"]).replace(use_kernels=False,
                                                **conf["changes"])

@@ -11,6 +11,7 @@ import math
 
 import numpy as np
 
+import backbones
 import flops
 
 
@@ -62,7 +63,7 @@ def attention_roofline(run, label):
     m, tr = run["model"], run["traffic"]
     lanes = tr["num_slots"] * tr["num_cores"] * tr["latent_shape"][0]
     seq = tr["latent_shape"][-2]
+    causal = backbones.load(m["family"]).ATTENTION_CAUSAL
     work = flops.flash_attention_call(lanes, m["num_heads"], seq, seq,
-                                      m["head_dim"], 2,
-                                      causal=m["family"] != "dense")
+                                      m["head_dim"], 2, causal=causal)
     return _kernel_share(run, label, work)

@@ -1,27 +1,25 @@
-"""Plain float32 reference: the two backbones, the drift wrapper and CHORDS.
+"""Plain float32 reference: the drift wrapper, the backbones' shared pieces
+and CHORDS.
 
 Written from the published descriptions and the configuration file's
 numbers, in straightforward ``jax.numpy`` at ``highest`` matmul precision.
 Where a configuration departs from its published source (its file's
 ``assumed``), the reference models the configuration, so it checks the
 network that is timed. It imports nothing of the program: weights come in
-as a pytree (drawn by ``bench/weights.py``), hyper-parameters from
+as a pytree (drawn by ``bench/weights.py``) in their stored dtype and are
+cast to float32 where they are read, hyper-parameters from
 ``bench/configs/<name>.json``.
 
 * Drift (rectified flow, velocity prediction): in-projection of the latent,
   sinusoidal time embedding through a two-layer SiLU MLP added to every
   token, the backbone, RMSNorm, out-projection.
-* Dense DiT backbone: pre-norm blocks of RoPE multi-head attention over all
-  tokens and a SwiGLU MLP, final RMSNorm.
-* Zamba2 backbone: Mamba2 layers (in-projection to z, x, B, C, dt; causal
-  depthwise convolution and SiLU over x, B, C; the selective state
-  recurrence ``s_t = exp(dt_t A) s_{t-1} + dt_t x_t B_t^T``,
+* The backbone: ``bench/backbones/<family>.py`` for the configuration's
+  ``model.family``, built from the pieces here: RoPE multi-head attention,
+  the SwiGLU MLP, RMSNorm, and Mamba2 layers (in-projection to z, x, B, C,
+  dt; causal depthwise convolution and SiLU over x, B, C; the selective
+  state recurrence ``s_t = exp(dt_t A) s_{t-1} + dt_t x_t B_t^T``,
   ``y_t = s_t C_t + D x_t``, unrolled over blocks of 64 tokens; SiLU(z)
-  gate, RMSNorm, out-projection), and after every ``attn_every`` layers one shared
-  block: ``concat(h, h0)`` projected to ``d_model``, attention and MLP at
-  that width, projected back and added (published Zamba2 attends at the
-  concatenated width, with two such blocks). Attention there is causal, as
-  the program runs hybrid backbones (ROADMAP R5).
+  gate, RMSNorm, out-projection).
 * CHORDS (paper Algorithm 1): K cores start at the init sequence; core k
   jumps ``k`` times along it, then takes unit Euler steps; whenever core
   k-1 stands where core k last took its snapshot, core k is rectified by
@@ -36,10 +34,13 @@ bfloat16.
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import List, NamedTuple, Optional
 
 import numpy as np
+
+import backbones
 
 FP8_MAX = 448.0  # largest finite float8_e4m3fn
 # tokens per block of the state recurrence: the recurrence is exact at any
@@ -52,12 +53,18 @@ def make_mm(quant: Optional[str]):
     import jax.numpy as jnp
 
     hi = jax.lax.Precision.HIGHEST
+
+    def f32(x):
+        return x.astype(jnp.float32)
+
     if quant is None:
-        return lambda spec, a, b: jnp.einsum(spec, a, b, precision=hi)
+        return lambda spec, a, b: jnp.einsum(spec, f32(a), f32(b),
+                                             precision=hi)
     if quant != "fp8":
         raise ValueError(f"unknown quantization {quant!r}")
 
     def q(x):
+        x = f32(x)
         scale = jnp.maximum(jnp.max(jnp.abs(x)), 1e-30) / FP8_MAX
         return (x / scale).astype(jnp.float8_e4m3fn).astype(jnp.float32) \
             * scale
@@ -67,7 +74,8 @@ def make_mm(quant: Optional[str]):
 
 def _rms(x, w, eps):
     import jax.numpy as jnp
-    return x * (1.0 / jnp.sqrt(jnp.mean(x * x, -1, keepdims=True) + eps)) * w
+    return (x * (1.0 / jnp.sqrt(jnp.mean(x * x, -1, keepdims=True) + eps))
+            * w.astype(jnp.float32))
 
 
 def _silu(x):
@@ -120,17 +128,10 @@ def _mlp(p, x, mm):
 
 
 def _layer(tree, i):
+    """Layer ``i`` of a stacked subtree, in float32."""
     import jax
-    return jax.tree_util.tree_map(lambda a: a[i], tree)
-
-
-def dense_backbone(p, m, h, mm):
-    eps = m["norm_eps"]
-    for i in range(m["num_layers"]):
-        lp = _layer(p["blocks"], i)
-        h = h + _attn(lp["attn"], m, _rms(h, lp["ln1"], eps), False, mm)
-        h = h + _mlp(lp["mlp"], _rms(h, lp["ln2"], eps), mm)
-    return _rms(h, p["final_norm"], eps)
+    import jax.numpy as jnp
+    return jax.tree_util.tree_map(lambda a: a[i].astype(jnp.float32), tree)
 
 
 def mamba2(p, m, x, mm):
@@ -183,26 +184,6 @@ def mamba2(p, m, x, mm):
     return mm("bsk,kd->bsd", y, p["out_proj"])
 
 
-def hybrid_backbone(p, m, h0, mm):
-    import jax.numpy as jnp
-    eps = m["norm_eps"]
-    sp = p["shared"]
-    h = h0
-    for i in range(m["num_layers"]):
-        lp = _layer(p["mamba"], i)
-        h = h + mamba2(lp["ssd"], m, _rms(h, lp["ln"], eps), mm)
-        if (i + 1) % m["attn_every"] == 0:
-            x = _rms(jnp.concatenate([h, h0], -1), sp["ln_in"], eps)
-            x = mm("bse,ed->bsd", x, sp["w_in"])
-            x = x + _attn(sp["attn"], m, _rms(x, sp["ln1"], eps), True, mm)
-            x = x + _mlp(sp["mlp"], _rms(x, sp["ln2"], eps), mm)
-            h = h + mm("bsd,de->bse", x, sp["w_out"])
-    return _rms(h, p["final_norm"], eps)
-
-
-BACKBONES = {"dense": dense_backbone, "hybrid": hybrid_backbone}
-
-
 def time_embedding(t, dim=256, max_period=1e4):
     import jax.numpy as jnp
     half = dim // 2
@@ -216,19 +197,18 @@ def drift(p, m, x, t, mm):
     h = mm("bsl,ld->bsd", x, p["in_proj"])
     te = _silu(mm("bk,kd->bd", time_embedding(t), p["t_mlp1"]))
     h = h + mm("bd,de->be", te, p["t_mlp2"])[:, None, :]
-    h = BACKBONES[m["family"]](p["backbone"], m, h, mm)
+    h = backbones.load(m["family"]).reference(p["backbone"], m, h, mm)
     h = _rms(h, p["out_norm"], m["norm_eps"])
     return mm("bsd,dl->bsl", h, p["out_proj"])
 
 
 def make_drift(params, model: dict, quant: Optional[str] = None):
-    """Jitted ``f(x [K, *latent], t [K]) -> [K, *latent]`` over float32
-    copies of ``params``; each core's latent is one batch row."""
+    """``f(x [K, *latent], t [K]) -> [K, *latent]``; each core's latent is
+    one batch row. ``params`` go into the jitted function as they are
+    stored and are cast to float32 where each is read, so no float32 copy
+    of the tree is made (``f.args[0] is params``)."""
     import jax
-    import jax.numpy as jnp
 
-    p32 = jax.tree_util.tree_map(lambda a: jnp.asarray(a, jnp.float32),
-                                 params)
     mm = make_mm(quant)
 
     @jax.jit
@@ -237,7 +217,7 @@ def make_drift(params, model: dict, quant: Optional[str] = None):
         flat = x.reshape((k,) + x.shape[-2:])
         return drift(p, model, flat, t, mm).reshape(x.shape)
 
-    return lambda x, t: f(p32, x, t)
+    return functools.partial(f, params)
 
 
 class Emission(NamedTuple):

@@ -141,10 +141,13 @@ def check_device(chips: int, rehearse: bool):
 # -- the program under test --------------------------------------------------
 
 def program_config(cell: dict, rehearse: bool):
-    """(program cfg, model numbers for the reference)."""
+    """(program cfg, model numbers for the reference). The model's family
+    must have its file, ``bench/backbones/<family>.py``."""
+    import backbones
     from repro.configs import get_config
 
     conf = cell["config"]
+    backbones.load(conf["model"]["family"])
     kern = conf["kernels"]
     if rehearse:
         cfg = get_config(conf["arch"], reduced=True).replace(
@@ -453,6 +456,13 @@ def main(argv=None, edit_cell=None) -> int:
         # the TPU runtime logs under /tmp/tpu_logs unless told otherwise
         os.environ.setdefault("TPU_LOG_DIR", os.path.join(args.out,
                                                           "tpu_logs"))
+    tr = cell["traffic"]
+    if args.rehearse:
+        tr = dict(tr, latent_shape=list(REHEARSAL_LATENT))
+        cell = dict(cell, traffic=tr)
+    latent_shape = tuple(tr["latent_shape"])
+    cfg, model = program_config(cell, args.rehearse)
+
     import jax
     import numpy as np
 
@@ -474,18 +484,13 @@ def main(argv=None, edit_cell=None) -> int:
     from repro.diffusion import init_wrapper
     from repro.obs import Tracer
 
-    tr = cell["traffic"]
-    if args.rehearse:
-        tr = dict(tr, latent_shape=list(REHEARSAL_LATENT))
-        cell = dict(cell, traffic=tr)
-    latent_shape = tuple(tr["latent_shape"])
-    cfg, model = program_config(cell, args.rehearse)
     rng = np.random.default_rng(args.seed)
     wseed, kseed = (int(x) for x in rng.integers(0, 2 ** 31 - 1, 2))
     structure = jax.eval_shape(
         lambda k: init_wrapper(cfg, latent_shape[-1], k, cfg.param_dtype),
         jax.random.PRNGKey(0))
-    params = jax.block_until_ready(weights.draw(structure, wseed))
+    params = jax.block_until_ready(weights.draw(structure, wseed,
+                                                model["family"]))
     keys = np.asarray(jax.random.split(jax.random.PRNGKey(kseed), N_KEYS))
     engine = build_engine(cfg, tr, params, latent_shape,
                           cell["config"]["kernels"]["round"],

@@ -48,7 +48,7 @@ def _params(cell, seed):
     structure = jax.eval_shape(
         lambda k: init_wrapper(cfg, latent[-1], k, cfg.param_dtype),
         jax.random.PRNGKey(0))
-    return cfg, model, weights.draw(structure, seed), latent
+    return cfg, model, weights.draw(structure, seed, model["family"]), latent
 
 
 @pytest.mark.parametrize("name", CELLS)

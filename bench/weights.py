@@ -13,16 +13,18 @@ Each leaf is drawn by its name:
   softplus of ``dt ~ log-uniform(1e-3, 1e-1)``; ``d_skip``: ones (the
   Mamba2 initialization);
 * every matrix: ``normal / sqrt(fan_in)``, with fan-in the contracted
-  width (``d_model`` for the q/k/v projections);
+  width (``d_model`` for the q/k/v projections): the product of the axes
+  after the leaf's stack axes (layers, experts; the family's ``STACKED``,
+  ``bench/backbones/<family>.py``) but the last;
 * the unused token embedding: zeros.
 """
 from __future__ import annotations
 
 import math
 
+import backbones
+
 NORMS = {"ln", "ln1", "ln2", "ln_in", "final_norm", "out_norm", "gate_norm"}
-# leaves stacked over layers: their first axis is the layer, not a width
-STACKED = {"blocks", "mamba"}
 
 
 def _fan_in(name: str, shape: tuple) -> int:
@@ -31,11 +33,11 @@ def _fan_in(name: str, shape: tuple) -> int:
     return max(1, math.prod(shape[:-1]))
 
 
-def _leaf(name: str, stacked: bool, shape, dtype, key):
+def _leaf(name: str, stack_axes: int, shape, dtype, key):
     import jax
     import jax.numpy as jnp
 
-    per = shape[1:] if stacked else shape
+    per = shape[stack_axes:]
     if name in NORMS:
         w = 1.0 + 0.1 * jax.random.normal(key, shape, jnp.float32)
     elif name == "a_log":
@@ -54,11 +56,12 @@ def _leaf(name: str, stacked: bool, shape, dtype, key):
     return w.astype(dtype)
 
 
-def draw(structure, seed: int):
-    """Weights shaped like ``structure`` (a pytree of ShapeDtypeStructs),
-    drawn from ``seed`` in one jitted program."""
+def draw(structure, seed: int, family: str):
+    """Weights shaped like ``structure`` (a pytree of ShapeDtypeStructs) of
+    a ``family`` backbone, drawn from ``seed`` in one jitted program."""
     import jax
 
+    stacked = backbones.load(family).STACKED
     paths, treedef = jax.tree_util.tree_flatten_with_path(structure)
 
     def keyname(k):
@@ -67,7 +70,7 @@ def draw(structure, seed: int):
     specs = []
     for path, leaf in paths:
         names = [keyname(k) for k in path]
-        specs.append((names[-1], bool(STACKED & set(names)),
+        specs.append((names[-1], sum(stacked.get(n, 0) for n in names),
                       tuple(leaf.shape), leaf.dtype))
 
     def make(key):
