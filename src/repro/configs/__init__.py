@@ -17,6 +17,7 @@ from repro.configs import (  # noqa: F401
     chords_dit,
     gemma_7b,
     internlm2_1_8b,
+    nemotron_3_nano,
     olmoe_1b_7b,
     qwen1_5_0_5b,
     qwen1_5_32b,

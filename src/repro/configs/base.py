@@ -21,7 +21,7 @@ from typing import Callable
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
-    family: str  # dense | moe | hybrid | ssm | encdec | vlm | audio
+    family: str  # dense | moe | hybrid | nemotron_h | ssm | encdec | vlm | audio
     num_layers: int
     d_model: int
     num_heads: int
@@ -31,7 +31,7 @@ class ModelConfig:
     head_dim: int = 0  # 0 -> d_model // num_heads
     # attention / embedding details
     qkv_bias: bool = False
-    act: str = "swiglu"  # swiglu | geglu
+    act: str = "swiglu"  # swiglu | geglu | relu2 (non-gated relu(x)^2)
     rope_theta: float = 10_000.0
     mrope_sections: tuple = ()  # qwen2-vl M-RoPE (t,h,w) sections of head_dim/2
     emb_scale: bool = False  # gemma: scale embeddings by sqrt(d_model)
@@ -42,13 +42,21 @@ class ModelConfig:
     experts_per_tok: int = 0
     num_shared_experts: int = 0
     moe_capacity_factor: float = 1.25
+    shared_d_ff: int = 0  # width of the one ungated shared expert (nemotron_h)
+    router: str = "softmax"  # softmax (moe family) | sigmoid (nemotron_h)
+    routed_scale: float = 1.0  # routed experts' output multiplier
     # SSM (mamba2 / zamba2)
     ssm_state: int = 0
     ssm_expand: int = 2
     ssm_head_dim: int = 64
     ssm_conv: int = 4
     ssm_chunk: int = 256
+    ssm_heads: int = 0  # 0 -> ssm_expand * d_model // ssm_head_dim
+    ssm_groups: int = 1  # B/C groups; head h reads group h // (heads / groups)
+    ssm_conv_bias: bool = False
     attn_every: int = 0  # zamba2: shared attention block cadence
+    # nemotron_h: one letter per layer, M Mamba2, E MoE, * attention
+    layer_pattern: str = ""
     # xLSTM
     slstm_every: int = 0  # 1 sLSTM block per this many blocks (rest mLSTM)
     mlstm_proj_factor: float = 2.0

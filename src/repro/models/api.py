@@ -3,7 +3,8 @@
 Every family module exposes (duck-typed):
   specs(cfg), forward_train(params, cfg, tokens, ...), prefill(...),
   decode_step(params, cfg, tokens, cache, ...), cache_specs / cache_axes /
-  init_cache, and forward_hidden (diffusion-denoiser role).
+  init_cache, and forward_hidden (diffusion-denoiser role). ``nemotron_h``
+  is a denoiser trunk only: specs and forward_hidden.
 """
 from __future__ import annotations
 
@@ -13,7 +14,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.dist.sharding import shard_act
-from repro.models import dense, encdec, moe, xlstm, zamba2
+from repro.models import dense, encdec, moe, nemotron_h, xlstm, zamba2
 from repro.utils import pspec
 
 _FAMILY = {
@@ -21,6 +22,7 @@ _FAMILY = {
     "vlm": dense,
     "moe": moe,
     "hybrid": zamba2,
+    "nemotron_h": nemotron_h,
     "ssm": xlstm,
     "encdec": encdec,
     "audio": encdec,
@@ -97,7 +99,8 @@ def forward_hidden(params, cfg: ModelConfig, embeds, **kw):
         kw.pop("causal", None)
         h, _ = mod.forward_hidden(params, cfg, embeds, causal=True, **kw)
         return h
-    if cfg.family == "ssm":
+    if cfg.family in ("ssm", "nemotron_h"):
+        # recurrent backbones are causal-only
         kw.pop("causal", None)
         return mod.forward_hidden(params, cfg, embeds, **kw)
     return mod.forward_hidden(params, cfg, embeds, **kw)

@@ -21,15 +21,22 @@ from repro.utils.pspec import spec
 
 
 def d_inner(cfg: ModelConfig) -> int:
+    if cfg.ssm_heads:
+        return cfg.ssm_heads * cfg.ssm_head_dim
     return cfg.ssm_expand * cfg.d_model
 
 
 def num_ssm_heads(cfg: ModelConfig) -> int:
-    return d_inner(cfg) // cfg.ssm_head_dim
+    return cfg.ssm_heads or d_inner(cfg) // cfg.ssm_head_dim
+
+
+def _bc_width(cfg: ModelConfig) -> int:
+    """Width of each of B and C: ``ssm_groups`` groups of ``ssm_state``."""
+    return cfg.ssm_groups * cfg.ssm_state
 
 
 def ssd_specs(cfg: ModelConfig, layers: Optional[int] = None) -> dict:
-    d, din, n, h, w = (cfg.d_model, d_inner(cfg), cfg.ssm_state, num_ssm_heads(cfg),
+    d, din, n, h, w = (cfg.d_model, d_inner(cfg), _bc_width(cfg), num_ssm_heads(cfg),
                        cfg.ssm_conv)
     conv_ch = din + 2 * n
     Ld = () if layers is None else (layers,)
@@ -38,7 +45,7 @@ def ssd_specs(cfg: ModelConfig, layers: Optional[int] = None) -> dict:
     def s(shape, axes, **kw):
         return spec(Ld + tuple(shape), La + tuple(axes), **kw)
 
-    return {
+    specs = {
         "in_proj": s((d, 2 * din + 2 * n + h), ("embed", "ffn")),
         "conv_w": s((w, conv_ch), ("conv", "ffn"), init="normal", scale=0.5),
         "a_log": s((h,), ("heads",), init="zeros"),
@@ -47,6 +54,9 @@ def ssd_specs(cfg: ModelConfig, layers: Optional[int] = None) -> dict:
         "gate_norm": s((din,), ("ffn",), init="ones"),
         "out_proj": s((din, d), ("ffn", "embed")),
     }
+    if cfg.ssm_conv_bias:
+        specs["conv_b"] = s((conv_ch,), ("ffn",), init="zeros")
+    return specs
 
 
 def _depthwise_causal_conv(x, w, state=None):
@@ -63,7 +73,7 @@ def _depthwise_causal_conv(x, w, state=None):
 
 
 def _split(cfg, proj):
-    din, n, h = d_inner(cfg), cfg.ssm_state, num_ssm_heads(cfg)
+    din, n = d_inner(cfg), _bc_width(cfg)
     z = proj[..., :din]
     xc = proj[..., din : 2 * din]
     b_ = proj[..., 2 * din : 2 * din + n]
@@ -72,22 +82,44 @@ def _split(cfg, proj):
     return z, xc, b_, c_, dt
 
 
-def _gated_norm(y, z, w, eps):
+def _gated_norm(y, z, w, eps, groups=1):
+    """RMSNorm of ``y * SiLU(z)``, over each of ``groups`` equal groups of
+    channels, times ``w``."""
     y = y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype)
     dt_ = y.dtype
     y = y.astype(jnp.float32)
+    if groups > 1:
+        y = y.reshape(y.shape[:-1] + (groups, y.shape[-1] // groups))
     y = y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True) + eps)
+    if groups > 1:
+        y = y.reshape(y.shape[:-2] + (-1,))
     return (y * w.astype(jnp.float32)).astype(dt_)
+
+
+def _conv_act(cfg, p, conv_in, conv_state):
+    """Causal depthwise conv (plus its bias, where the config has one), SiLU."""
+    conv_out, new_conv = _depthwise_causal_conv(conv_in, p["conv_w"].astype(conv_in.dtype),
+                                                conv_state)
+    if cfg.ssm_conv_bias:
+        conv_out = conv_out + p["conv_b"].astype(conv_out.dtype)
+    return jax.nn.silu(conv_out), new_conv
 
 
 def ssd_forward(p, cfg: ModelConfig, x, conv_state=None, ssm_state=None):
     """Chunked SSD. x: [B, S, D] -> (y [B, S, D], (conv_state, ssm_state)).
+
+    With ``ssm_groups`` G > 1, B and C come in G groups and head ``h``
+    reads group ``h // (H / G)``: the scan runs with heads split as
+    ``[G, H / G]``, and the gated norm normalises each group's channels.
 
     Its parts run under the named scopes ``mamba2.in_proj``,
     ``mamba2.conv``, ``mamba2.scan`` and ``mamba2.out``
     (``repro.obs.scopes``)."""
     bsz, s, _ = x.shape
     din, n, h, hd = d_inner(cfg), cfg.ssm_state, num_ssm_heads(cfg), cfg.ssm_head_dim
+    grp, gn = cfg.ssm_groups, _bc_width(cfg)
+    # the head and B/C axes: [H] and [] with one group, [G, H/G] and [G]
+    hs, gs = ((h,), ()) if grp == 1 else ((grp, h // grp), (grp,))
     lc = min(cfg.ssm_chunk, s)
     assert s % lc == 0, (s, lc)
     nc = s // lc
@@ -97,30 +129,31 @@ def ssd_forward(p, cfg: ModelConfig, x, conv_state=None, ssm_state=None):
     with jax.named_scope("mamba2.conv"):
         z, xc, b_, c_, dt = _split(cfg, proj)
         conv_in = jnp.concatenate([xc, b_, c_], axis=-1)
-        conv_out, new_conv = _depthwise_causal_conv(conv_in, p["conv_w"].astype(x.dtype),
-                                                    conv_state)
-        conv_out = jax.nn.silu(conv_out)
+        conv_out, new_conv = _conv_act(cfg, p, conv_in, conv_state)
         xc = conv_out[..., :din]
-        b_ = conv_out[..., din : din + n]
-        c_ = conv_out[..., din + n :]
+        b_ = conv_out[..., din : din + gn]
+        c_ = conv_out[..., din + gn :]
     with jax.named_scope("mamba2.scan"):
         dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"].astype(jnp.float32))
         a = -jnp.exp(p["a_log"].astype(jnp.float32))  # [H]
         loga = dt * a[None, None, :]  # [B, S, H]  (log decay, <= 0)
 
-        xh = xc.reshape(bsz, nc, lc, h, hd)
-        bh = b_.reshape(bsz, nc, lc, n).astype(jnp.float32)
-        ch = c_.reshape(bsz, nc, lc, n).astype(jnp.float32)
-        dth = dt.reshape(bsz, nc, lc, h)
-        logc = loga.reshape(bsz, nc, lc, h)
-        xh = shard_act(xh, ("batch", None, None, "heads", None))
+        xh = xc.reshape((bsz, nc, lc) + hs + (hd,))
+        bh = b_.reshape((bsz, nc, lc) + gs + (n,)).astype(jnp.float32)
+        ch = c_.reshape((bsz, nc, lc) + gs + (n,)).astype(jnp.float32)
+        dth = dt.reshape((bsz, nc, lc) + hs)
+        logc = loga.reshape((bsz, nc, lc) + hs)
+        xh = shard_act(xh, ("batch", None, None) + (None,) * len(gs) + ("heads", None))
 
         mask = jnp.tril(jnp.ones((lc, lc), bool))
-        init = (jnp.zeros((bsz, h, hd, n), jnp.float32) if ssm_state is None
-                else ssm_state.astype(jnp.float32))
+        init = (jnp.zeros((bsz,) + hs + (hd, n), jnp.float32) if ssm_state is None
+                else ssm_state.astype(jnp.float32).reshape((bsz,) + hs + (hd, n)))
 
         mode = resolve_kernel_mode(cfg.use_kernels)
         if mode is not None:
+            if grp > 1:
+                raise NotImplementedError(
+                    f"the ssd_scan kernel has no grouped B/C (ssm_groups={grp})")
             # Pallas intra-chunk path (repro.kernels.ssd_scan): every chunk's
             # masked decay-attention block and chunk-local state run in one
             # kernel launch over a (batch*chunks, heads) grid; only the tiny
@@ -149,6 +182,10 @@ def ssd_forward(p, cfg: ModelConfig, x, conv_state=None, ssm_state=None):
                        for t in (y_intra, s_local, cum, ch, total))
             final_state, y = jax.lax.scan(body, init, xs)
         else:
+            # einsum specs name the group axis "G"; with one group it is absent
+            ein = lambda spec: spec.replace("G", "g" if gs else "")  # noqa: E731
+            mask_ix = (None, slice(None), slice(None)) + (None,) * len(hs)
+
             def body(carry, inp):
                 # carry: inter-chunk state [B,H,hd,N]; one chunk's tensors:
                 xh_c, bh_c, ch_c, dth_c, logc_c = inp
@@ -157,40 +194,44 @@ def ssd_forward(p, cfg: ModelConfig, x, conv_state=None, ssm_state=None):
                 xdt = xh_c.astype(jnp.float32) * dth_c[..., None]  # [B,Lc,H,hd]
                 # intra-chunk: G[l,m] = C_l . B_m ; M[h,l,m] = exp(cum_l - cum_m),
                 # m<=l
-                g = jnp.einsum("bln,bmn->blm", ch_c, bh_c)
+                g = jnp.einsum(ein("blGn,bmGn->blGm"), ch_c, bh_c)
                 dlog = cum[:, :, None, :] - cum[:, None, :, :]  # [B,Lc(l),Lc(m),H]
-                mexp = jnp.where(mask[None, :, :, None], jnp.exp(dlog), 0.0)
-                y_intra = jnp.einsum("blm,blmh,bmhp->blhp", g, mexp, xdt)
+                mexp = jnp.where(mask[mask_ix], jnp.exp(dlog), 0.0)
+                y_intra = jnp.einsum(ein("blGm,blmGh,bmGhp->blGhp"), g, mexp, xdt)
                 # inter-chunk contribution from the carried state
-                y_inter = jnp.einsum("blh,bln,bhpn->blhp", jnp.exp(cum), ch_c,
+                y_inter = jnp.einsum(ein("blGh,blGn,bGhpn->blGhp"), jnp.exp(cum), ch_c,
                                      carry)
                 # chunk-local state + recurrence
                 w_local = jnp.exp(total[:, None, :] - cum)  # [B,Lc,H]
-                s_local = jnp.einsum("bmh,bmhp,bmn->bhpn", w_local, xdt, bh_c)
-                new = jnp.exp(total)[:, :, None, None] * carry + s_local
+                s_local = jnp.einsum(ein("bmGh,bmGhp,bmGn->bGhpn"), w_local, xdt, bh_c)
+                decay = jnp.exp(total)[(slice(None),) * total.ndim + (None, None)]
+                new = decay * carry + s_local
                 return new, (y_intra + y_inter).astype(x.dtype)
 
             xs = tuple(jnp.moveaxis(t, 1, 0) for t in (xh, bh, ch, dth, logc))
             final_state, y = jax.lax.scan(body, init, xs)
+        if gs:
+            final_state = final_state.reshape(bsz, h, hd, n)
         y = jnp.moveaxis(y, 0, 1).reshape(bsz, s, h, hd).astype(jnp.float32)
         y = y + xh.reshape(bsz, s, h, hd).astype(jnp.float32) * p["d_skip"].astype(jnp.float32)[None, None, :, None]
         y = y.reshape(bsz, s, din).astype(x.dtype)
     with jax.named_scope("mamba2.out"):
-        y = _gated_norm(y, z, p["gate_norm"], cfg.norm_eps)
+        y = _gated_norm(y, z, p["gate_norm"], cfg.norm_eps, grp)
         out = jnp.einsum("bsk,kd->bsd", y, p["out_proj"].astype(x.dtype))
     return out, (new_conv, final_state.astype(jnp.float32))
 
 
 def ssd_decode_step(p, cfg: ModelConfig, x, conv_state, ssm_state):
     """x: [B, 1, D]; O(1) recurrent update. Returns (y, (conv_state, ssm_state))."""
+    if cfg.ssm_groups > 1:
+        raise NotImplementedError("ssd_decode_step has no grouped B/C")
     bsz = x.shape[0]
     din, n, h, hd = d_inner(cfg), cfg.ssm_state, num_ssm_heads(cfg), cfg.ssm_head_dim
     proj = jnp.einsum("bsd,dk->bsk", x, p["in_proj"].astype(x.dtype))
     z, xc, b_, c_, dt = _split(cfg, proj)
     conv_in = jnp.concatenate([xc, b_, c_], axis=-1)  # [B,1,C]
-    conv_out, new_conv = _depthwise_causal_conv(conv_in, p["conv_w"].astype(x.dtype),
-                                                conv_state)
-    conv_out = jax.nn.silu(conv_out)[:, 0]  # [B, C]
+    conv_out, new_conv = _conv_act(cfg, p, conv_in, conv_state)
+    conv_out = conv_out[:, 0]  # [B, C]
     xc = conv_out[..., :din].reshape(bsz, h, hd)
     b_ = conv_out[..., din : din + n].astype(jnp.float32)
     c_ = conv_out[..., din + n :].astype(jnp.float32)
@@ -213,7 +254,8 @@ def ssd_state_specs(cfg: ModelConfig, batch, layers: int, dtype=jnp.float32):
     din, n, h, hd, w = (d_inner(cfg), cfg.ssm_state, num_ssm_heads(cfg),
                         cfg.ssm_head_dim, cfg.ssm_conv)
     return {
-        "conv": jax.ShapeDtypeStruct((layers, batch, w - 1, din + 2 * n), jnp.bfloat16),
+        "conv": jax.ShapeDtypeStruct((layers, batch, w - 1, din + 2 * _bc_width(cfg)),
+                                     jnp.bfloat16),
         "ssm": jax.ShapeDtypeStruct((layers, batch, h, hd, n), dtype),
     }
 

@@ -1,12 +1,19 @@
-"""Mixture-of-Experts transformer (qwen2-moe-a2.7b, olmoe-1b-7b).
+"""Mixture-of-Experts transformer (qwen2-moe-a2.7b, olmoe-1b-7b), and the
+dropless routed-expert layer of the ``nemotron_h`` family.
 
-MoE layer uses a sort-based dropping dispatch (MaxText-style "permute"):
-tokens are routed top-k, sorted by expert id *within expander groups* (one
-group per data shard so routing never crosses the DP axis), packed into
-[groups, experts, capacity, d] buffers and processed with batched expert
-einsums sharded experts->"model". Overflowing tokens are dropped (capacity
-factor config). This keeps compiled FLOPs ~ active-expert FLOPs instead of
-the dense E/k-times overcompute.
+The ``moe`` family's layer (:func:`moe_ffn`) uses a sort-based dropping
+dispatch (MaxText-style "permute"): tokens are routed top-k, sorted by
+expert id *within expander groups* (one group per data shard so routing
+never crosses the DP axis), packed into [groups, experts, capacity, d]
+buffers and processed with batched expert einsums sharded
+experts->"model". Overflowing tokens are dropped (capacity factor config).
+This keeps compiled FLOPs ~ active-expert FLOPs instead of the dense
+E/k-times overcompute.
+
+:func:`dropless_moe` drops nothing, whatever the routing: in a denoiser a
+dropped token changes the latent. Its (token, expert) rows are sorted by
+expert and the expert products run as grouped matmuls over the real group
+sizes (``jax.lax.ragged_dot``).
 """
 from __future__ import annotations
 
@@ -114,6 +121,107 @@ def moe_ffn(p, cfg: ModelConfig, x, num_groups: int = 1):
         gate = jax.nn.sigmoid(jnp.einsum("bsd,dz->bsz", x, sh["gate"].astype(x.dtype)))
         out = out + gate * shared_out
     return out.astype(x.dtype)
+
+
+def dropless_specs(cfg: ModelConfig) -> dict:
+    """Router ``[d, E]``, the expert stacks under ``experts`` (``[E, d, f]``
+    up, ``[E, f, d]`` down) and, with ``shared_d_ff``, one shared expert."""
+    d, f, e = cfg.d_model, cfg.d_ff, cfg.num_experts
+    specs = {
+        "router": spec((d, e), ("embed", "experts")),
+        "experts": {
+            "w_up": spec((e, d, f), ("experts", "embed", "ffn")),
+            "w_down": spec((e, f, d), ("experts", "ffn", "embed")),
+        },
+    }
+    if cfg.shared_d_ff:
+        specs["shared"] = {
+            "w_up": spec((d, cfg.shared_d_ff), ("embed", "ffn")),
+            "w_down": spec((cfg.shared_d_ff, d), ("ffn", "embed")),
+        }
+    return specs
+
+
+def _relu2(x):
+    return jnp.square(jax.nn.relu(x))
+
+
+def route(cfg: ModelConfig, scores):
+    """Top-k of float32 router ``scores`` [T, E] -> (weights [T, k] float32,
+    experts [T, k] int32). Ties go to the lower expert index
+    (``jax.lax.top_k``); the k weights are renormalised to sum to 1 and
+    scaled by ``routed_scale``."""
+    w, idx = jax.lax.top_k(scores, cfg.experts_per_tok)
+    w = w / jnp.sum(w, axis=-1, keepdims=True) * cfg.routed_scale
+    return w, idx.astype(jnp.int32)
+
+
+def dropless_moe(p, cfg: ModelConfig, x):
+    """x: [B, S, D] -> [B, S, D]: routed relu^2 experts, no token dropped,
+    plus the ungated shared expert (:func:`_dropless`).
+
+    Tokens are independent, so under ``vmap`` (the round maps the drift over
+    cores and slots) the mapped axis folds into the tokens: every lane's
+    rows go through one sort and one pair of grouped matmuls."""
+    leaves, tree = jax.tree_util.tree_flatten(p)
+
+    @jax.custom_batching.custom_vmap
+    def layer(x, *leaves):
+        return _dropless(jax.tree_util.tree_unflatten(tree, leaves), cfg, x)
+
+    @layer.def_vmap
+    def _fold(axis_size, in_batched, x, *leaves):
+        if any(in_batched[1:]):
+            raise NotImplementedError("dropless_moe maps over tokens, not weights")
+        return layer(x.reshape((-1,) + x.shape[2:]), *leaves).reshape(x.shape), True
+
+    return layer(x, *leaves)
+
+
+def _dropless(p, cfg: ModelConfig, x):
+    """The layer on one batch of tokens.
+
+    The router's sigmoid scores are computed in float32. Each
+    token's k (token, expert) rows are sorted by expert, gathered, run
+    through the two grouped matmuls over the real group sizes, put back in
+    token order and summed with the gate weights. Parts run under the named
+    scopes ``moe.router``, ``moe.dispatch`` (sort, gather, un-permute,
+    combine), ``moe.experts`` and ``moe.shared`` (``repro.obs.scopes``)."""
+    if (cfg.act, cfg.router) != ("relu2", "sigmoid"):
+        raise ValueError(f"dropless_moe runs relu2 experts under a sigmoid router, "
+                         f"not {cfg.act!r} under {cfg.router!r}")
+    b, s, d = x.shape
+    e, k = cfg.num_experts, cfg.experts_per_tok
+    t = b * s
+    xf = x.reshape(t, d)
+    with jax.named_scope("moe.router"):
+        logits = jnp.einsum("td,de->te", xf.astype(jnp.float32),
+                            p["router"].astype(jnp.float32),
+                            precision=jax.lax.Precision.HIGHEST)
+        gate, expert = route(cfg, jax.nn.sigmoid(logits))  # [T, k]
+    with jax.named_scope("moe.dispatch"):
+        flat = expert.reshape(t * k)
+        order = jnp.argsort(flat, stable=True)  # rows grouped by expert
+        # compare-and-sum, not jnp.bincount: timed alone on a v5e at a round's
+        # 196,608 rows, 0.69 ms against bincount's (scatter-add) 2.39 ms
+        sizes = jnp.sum(flat[:, None] == jnp.arange(e, dtype=flat.dtype), axis=0,
+                        dtype=jnp.int32)
+        rows = xf[order // k]
+    with jax.named_scope("moe.experts"):
+        ex = p["experts"]
+        hid = _relu2(jax.lax.ragged_dot(rows, ex["w_up"].astype(x.dtype), sizes))
+        out_rows = jax.lax.ragged_dot(hid, ex["w_down"].astype(x.dtype), sizes)
+    with jax.named_scope("moe.dispatch"):
+        inv = jnp.zeros_like(order).at[order].set(
+            jnp.arange(t * k, dtype=order.dtype), unique_indices=True)
+        back = out_rows[inv].reshape(t, k, d).astype(jnp.float32)
+        out = jnp.sum(back * gate[..., None], axis=1)
+    if "shared" in p:
+        with jax.named_scope("moe.shared"):
+            sh = p["shared"]
+            hs = _relu2(jnp.einsum("td,df->tf", xf, sh["w_up"].astype(x.dtype)))
+            out = out + jnp.einsum("tf,fd->td", hs, sh["w_down"].astype(x.dtype))
+    return out.astype(x.dtype).reshape(b, s, d)
 
 
 # ---------------------------------------------------------------------------

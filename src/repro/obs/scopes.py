@@ -12,6 +12,16 @@ innermost scope of the vocabulary on that path:
   ``mamba2.scan`` (softplus and decay through the chunked scan or its
   kernel, and ``y``), ``mamba2.out`` (gated norm and out projection);
   Zamba2's ``shared_block`` holds its own ``norm``/``attn``/``mlp``;
+* the dropless MoE layer (``models/moe.py`` ``dropless_moe``):
+  ``moe.router`` (the float32 router matmul, sigmoid scores, top-k and
+  the gate weights), ``moe.dispatch`` (the sort of the (token,
+  expert) rows by expert, group sizes, the gather of the rows, the
+  un-permute, the gate-weighted combine and the layer's residual add),
+  ``moe.experts`` (the two grouped expert matmuls and relu^2 between),
+  ``moe.shared`` (the shared expert); the Nemotron-H family's Mamba2 and
+  attention layers use ``mamba2.*`` and ``attn``. On the TPU the grouped
+  matmuls become ops named ``ragged-dot-*`` whose ``op_name`` has lost the
+  path; :data:`LOWERED` charges them to ``moe.experts``;
 * the diffusion wrapper: ``wrapper.in`` (latent in-projection and time
   embedding), ``wrapper.out`` (output norm and projection);
 * ``chords.step``: a whole CHORDS round (``serve/executor.py``'s round
@@ -35,8 +45,14 @@ import re
 from typing import Dict, NamedTuple, Optional
 
 SCOPES = ("norm", "attn", "mlp", "mamba2.in_proj", "mamba2.conv",
-          "mamba2.scan", "mamba2.out", "shared_block", "wrapper.in",
+          "mamba2.scan", "mamba2.out", "shared_block", "moe.router",
+          "moe.dispatch", "moe.experts", "moe.shared", "wrapper.in",
           "wrapper.out", "chords.step", "drift")
+
+# ops whose op_name a TPU compiler pass replaces with its own, dropping the
+# scope path: XLA lowers ``jax.lax.ragged_dot``, which only the dropless
+# MoE's expert matmuls call, to ops named ``ragged-dot-*``
+LOWERED = (("ragged-dot-", "moe.experts"),)
 
 _TOKEN = re.compile(r"[/()]")
 _INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = (\S+)")
@@ -57,9 +73,13 @@ def _chain(op_name: str) -> tuple:
 
 
 def scope_of(op_name: str) -> Optional[str]:
-    """Innermost vocabulary scope on an ``op_name`` path, or None."""
+    """Innermost vocabulary scope on an ``op_name`` path, or None; an op
+    that a compiler pass named after itself takes :data:`LOWERED`'s scope."""
     chain = _chain(op_name)
-    return chain[-1] if chain else None
+    if chain:
+        return chain[-1]
+    return next((scope for prefix, scope in LOWERED
+                 if op_name.startswith(prefix)), None)
 
 
 def _unnested(chains: set) -> bool:

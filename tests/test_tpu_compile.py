@@ -102,3 +102,34 @@ def test_fused_step_rectify_accept_compiles(one_chip):
         functools.partial(fused_step_rectify_accept, interpret=False),
         *_rectify_shapes(7), sharding=one_chip)
     assert "tpu_custom_call" in text
+
+
+def test_nemotron_round_fits_one_chip(one_chip):
+    # one CHORDS round of nemotron-3-nano-7l (the benchmark's first 7 layers,
+    # MEMEM*E, every width and all 128 experts) over S*K = 8 latents of
+    # 4096 tokens: its weights and temporaries fit a v5e's 16 GB, and the
+    # grouped expert matmuls lower to XLA's ragged-dot kernel, the ops
+    # bench/metrics/expert_roofline.py reads
+    from repro.configs import get_config
+    from repro.core.ode import uniform_tgrid
+    from repro.diffusion import init_wrapper, make_drift
+    from repro.serve.executor import GridSpec, _grid_fns, _slot_state_structs
+
+    cfg = get_config("nemotron-3-nano-30b-a3b").replace(
+        num_layers=7, layer_pattern="MEMEM*E")
+    spec = GridSpec(num_slots=2, num_cores=4, latent_shape=(1, 4096, 64),
+                    donate=True)
+    fn = _grid_fns(make_drift(None, cfg), uniform_tgrid(20), 20, spec,
+                   True)["round"]
+    on_chip = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        tree)
+    params = jax.eval_shape(
+        lambda k: init_wrapper(cfg, 64, k, cfg.param_dtype),
+        jax.random.PRNGKey(0))
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        on_chip(params), on_chip(_slot_state_structs(spec))).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes > 8e9  # 4.03 B bf16 parameters
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9, mem
+    assert "%ragged-dot-none" in compiled.as_text()
