@@ -21,6 +21,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.xla_metadata import set_xla_metadata
 
 from repro.configs.base import ModelConfig
 from repro.dist.sharding import shard_act
@@ -146,6 +147,63 @@ def _relu2(x):
     return jnp.square(jax.nn.relu(x))
 
 
+# What _ragged_tiles models of XLA's ragged-dot kernel on a TPU v5e: the
+# bf16 MXU peak and HBM bandwidth (published), a cost per grid step, and the
+# scoped VMEM a tiling may take. Per the grid step: the default tiling's
+# 160,965 steps a call at a round's 196,608 rows run 45-48 ms past the modelled
+# bytes (nemotron3-nano.img-backlog). VMEM: compiled for a described v5e
+# over a grid of tilings, every working set below 12 MiB compiles, and from
+# there up the compiler, adding scratch of its own, can pass its 16 MiB.
+_PEAK_FLOP_S, _HBM_BYTES_S = 197e12, 819e9
+_STEP_S = 0.29e-6
+_VMEM_BYTES = 12 << 20
+
+
+def _ragged_time(rows: int, k: int, n: int, groups: int, itemsize: int, tiles):
+    """Modelled seconds of ``jax.lax.ragged_dot`` of ``[rows, k]`` rows against
+    ``[groups, k, n]`` on XLA's TPU kernel under ``tiles = (tm, tk, tn)``.
+
+    The kernel visits a row tile once for each group in it, so its grid has
+    up to ``ceil(rows / tm) + groups - 1`` row tiles, each computed whole over
+    k and n padded to the tiles. Time: the padded FLOPs at the peak or the
+    bytes at HBM bandwidth (lhs once per column tile, rhs once per row tile,
+    the output once), whichever is longer, plus the grid's steps at
+    ``_STEP_S``."""
+    tm, tk, tn = tiles
+    m_tiles = -(-rows // tm) + groups - 1
+    kt, nt = -(-k // tk), -(-n // tn)
+    flops = 2 * m_tiles * tm * kt * tk * nt * tn
+    moved = itemsize * m_tiles * (kt * tk * (tm * nt + nt * tn) + tm * nt * tn)
+    return (max(flops / _PEAK_FLOP_S, moved / _HBM_BYTES_S)
+            + m_tiles * kt * nt * _STEP_S)
+
+
+def _ragged_tiles(rows: int, k: int, n: int, groups: int, itemsize: int):
+    """``(tm, tk, tn)`` for ``jax.lax.ragged_dot`` of ``[rows, k]`` rows
+    against ``[groups, k, n]`` on XLA's TPU kernel: the tiling of least
+    :func:`_ragged_time` whose working set (double-buffered lhs and rhs
+    tiles, the output tile and its float32 accumulator) fits the scoped
+    VMEM. tk and tn are multiples of 128 up to the dim rounded up to 128; tm
+    is one of 128..2048, at most ``rows`` rounded up to 8."""
+    up = lambda v, a: -(-v // a) * a  # noqa: E731
+    fits = [(tm, tk, tn)
+            for tm in sorted({min(t, up(rows, 8)) for t in (128, 256, 512, 1024, 2048)})
+            for tk in range(128, up(k, 128) + 1, 128)
+            for tn in range(128, up(n, 128) + 1, 128)
+            if 2 * (tm * tk + tk * tn) * itemsize + tm * tn * (2 * itemsize + 4)
+            < _VMEM_BYTES]
+    return min(fits, key=lambda t: (_ragged_time(rows, k, n, groups, itemsize, t), t))
+
+
+def _tiled_ragged_dot(lhs, rhs, sizes):
+    """``jax.lax.ragged_dot`` under the tiles :func:`_ragged_tiles` picks
+    for its shapes (XLA's TPU kernel reads them; other backends ignore it)."""
+    tiles = _ragged_tiles(lhs.shape[0], lhs.shape[1], rhs.shape[2], rhs.shape[0],
+                          lhs.dtype.itemsize)
+    with set_xla_metadata(ragged_dot_tiling=",".join(map(str, tiles))):
+        return jax.lax.ragged_dot(lhs, rhs, sizes)
+
+
 def route(cfg: ModelConfig, scores):
     """Top-k of float32 router ``scores`` [T, E] -> (weights [T, k] float32,
     experts [T, k] int32). Ties go to the lower expert index
@@ -209,8 +267,8 @@ def _dropless(p, cfg: ModelConfig, x):
         rows = xf[order // k]
     with jax.named_scope("moe.experts"):
         ex = p["experts"]
-        hid = _relu2(jax.lax.ragged_dot(rows, ex["w_up"].astype(x.dtype), sizes))
-        out_rows = jax.lax.ragged_dot(hid, ex["w_down"].astype(x.dtype), sizes)
+        hid = _relu2(_tiled_ragged_dot(rows, ex["w_up"].astype(x.dtype), sizes))
+        out_rows = _tiled_ragged_dot(hid, ex["w_down"].astype(x.dtype), sizes)
     with jax.named_scope("moe.dispatch"):
         inv = jnp.zeros_like(order).at[order].set(
             jnp.arange(t * k, dtype=order.dtype), unique_indices=True)

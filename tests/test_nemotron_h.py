@@ -10,7 +10,10 @@ the dropless MoE layer, grouped Mamba2 B/C, the family as a denoiser.
 * with one group, ``ssd_forward`` for ``zamba2-2.7b`` reduced gives the
   output recorded from the tree before grouped B/C existed;
 * the family through ``forward_hidden``: shape, finite, causal;
-* XLA's TPU ops for the grouped matmuls are charged to ``moe.experts``.
+* XLA's TPU ops for the grouped matmuls are charged to ``moe.experts``;
+* the ragged-dot tiles: aligned, inside the scoped VMEM, clamped at tiny
+  shapes, the same on every call, and carried by both grouped matmuls of
+  the round's lowering for a TPU.
 """
 from __future__ import annotations
 
@@ -228,3 +231,58 @@ def test_lowered_ragged_dot_ops_take_the_experts_scope():
     assert scopes["ragged-dot-none.1"].scope == "moe.experts"
     assert scopes["ragged-dot-metadata"].scope == "moe.experts"
     assert scopes["gather.2"].scope == "moe.dispatch"
+
+
+CELL_ROWS = 196608  # nemotron3-nano.img-backlog: 8 latents x 4096 tokens x 6 experts
+
+
+@pytest.mark.parametrize("rows,k,n,groups,itemsize", [
+    (CELL_ROWS, 2688, 1856, 128, 2),
+    (CELL_ROWS, 1856, 2688, 128, 2),
+    (CELL_ROWS, 2688, 1856, 128, 4),
+    (96, D, F, E, 4),
+    (5, 3, 7, 1, 2),
+], ids=["cell-up", "cell-down", "cell-up-f32", "tiny", "under-one-tile"])
+def test_ragged_tiles_are_aligned_and_fit_vmem(rows, k, n, groups, itemsize):
+    up = lambda v, a: -(-v // a) * a  # noqa: E731
+    tm, tk, tn = moe._ragged_tiles(rows, k, n, groups, itemsize)
+    assert tm % 8 == 0 and tk % 128 == 0 and tn % 128 == 0
+    assert 8 <= tm <= min(2048, up(rows, 8))
+    assert 128 <= tk <= up(k, 128) and 128 <= tn <= up(n, 128)
+    vmem = 2 * (tm * tk + tk * tn) * itemsize + tm * tn * (2 * itemsize + 4)
+    assert vmem < moe._VMEM_BYTES
+    assert moe._ragged_tiles(rows, k, n, groups, itemsize) == (tm, tk, tn)
+    if rows < 128:  # one row tile, the smallest the kernel may take
+        assert (tm, tk, tn) == (up(rows, 8), 128, 128)
+
+
+def test_ragged_tiles_beat_xla_default_at_the_cell():
+    # at the cell's shapes the pick models under half the time of XLA's
+    # default 512x128x128, and the up and down calls (k and n swapped) get
+    # tiles of their own
+    picks = {}
+    for name, (k, n) in {"up": (2688, 1856), "down": (1856, 2688)}.items():
+        picks[name] = moe._ragged_tiles(CELL_ROWS, k, n, 128, 2)
+        t = lambda tiles: moe._ragged_time(CELL_ROWS, k, n, 128, 2, tiles)  # noqa: E731
+        assert t(picks[name]) < t((512, 128, 128)) / 2, picks[name]
+    assert picks["up"] != picks["down"]
+
+
+def test_round_lowering_carries_tiles_on_both_grouped_matmuls():
+    # the round maps the drift over slots and cores; the folded layer's two
+    # ragged dots, lowered for a TPU at the cell's shapes, carry the tiles
+    # picked for their own shapes
+    cfg = get_config("nemotron-3-nano-30b-a3b")
+    p = jax.eval_shape(lambda key: init_params(moe.dropless_specs(cfg), key, jnp.bfloat16),
+                       jax.random.PRNGKey(0))
+    x = jax.ShapeDtypeStruct((2, 4, 1, 4096, cfg.d_model), jnp.bfloat16)
+
+    def lanes(p, x):
+        return jax.vmap(jax.vmap(lambda xx: moe.dropless_moe(p, cfg, xx)))(x)
+
+    text = jax.jit(lanes).trace(p, x).lower(lowering_platforms=("tpu",)).as_text()
+    got = [line.split('ragged_dot_tiling = "')[1].split('"')[0]
+           for line in text.splitlines() if "chlo.ragged_dot" in line]
+    d, ff, e = cfg.d_model, cfg.d_ff, cfg.num_experts
+    want = [moe._ragged_tiles(CELL_ROWS, d, ff, e, 2), moe._ragged_tiles(CELL_ROWS, ff, d, e, 2)]
+    assert got == [",".join(map(str, t)) for t in want]

@@ -133,3 +133,21 @@ def test_nemotron_round_fits_one_chip(one_chip):
     assert mem.argument_size_in_bytes > 8e9  # 4.03 B bf16 parameters
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9, mem
     assert "%ragged-dot-none" in compiled.as_text()
+
+
+@pytest.mark.parametrize("k,n", [(2688, 1856), (1856, 2688)], ids=["up", "down"])
+def test_nemotron_grouped_matmul_compiles_with_picked_tiles(one_chip, k, n):
+    # a round's 196,608 expert rows over 128 experts, as in
+    # nemotron3-nano.img-backlog: the compiler takes the tiles _ragged_tiles
+    # picks (a tiling over the scoped VMEM is refused here), and the op
+    # keeps the name bench/metrics/expert_roofline.py reads
+    from repro.models import moe
+
+    rows, experts = 196608, 128
+    tm, tk, tn = moe._ragged_tiles(rows, k, n, experts, 2)
+    text = _compiled_text(
+        moe._tiled_ragged_dot, ((rows, k), jnp.bfloat16),
+        ((experts, k, n), jnp.bfloat16), ((experts,), jnp.int32),
+        sharding=one_chip)
+    assert f'ragged_dot_tiling="{tm},{tk},{tn}"' in text
+    assert "%ragged-dot-none" in text
